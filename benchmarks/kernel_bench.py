@@ -51,8 +51,9 @@ def run():
     seg = jnp.ones(t, jnp.int32)
     pos = jnp.arange(t)
 
+    interpret = ops.interpret_on(q.sharding)
     fa = jax.jit(lambda *a: ops.flash_attention(*a, d ** -0.5, True, 0,
-                                                0.0, 128, 128))
+                                                0.0, 128, 128, interpret))
     us = timeit(lambda: jax.block_until_ready(
         fa(q, k, v, seg, seg, pos, pos)))
     rows.append(("kernel.flash_attention.pallas_interp", us,
@@ -67,7 +68,7 @@ def run():
     tt, vv = 256, 8192
     logits = jnp.array(rng.randn(tt, vv), jnp.bfloat16)
     labels = jnp.array(rng.randint(0, vv, tt), jnp.int32)
-    ce = jax.jit(ops.fused_softmax_xent)
+    ce = jax.jit(lambda lg, lb: ops.fused_softmax_xent(lg, lb, interpret))
     us = timeit(lambda: jax.block_until_ready(ce(logits, labels)))
     rows.append(("kernel.fused_ce.pallas_interp", us, f"T={tt} V={vv}"))
     cr = jax.jit(lambda lg, lb: ref.fused_ce_ref(lg, lb)[0])
@@ -113,8 +114,7 @@ def ring_run(iters: int = 2):
     from repro.core.ring import ring_attention
 
     hdp = max(1, jax.device_count())
-    mesh = compat.make_mesh((hdp, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((hdp, 1), ("data", "model"))
     compat.set_mesh(mesh)
     c, h, g, d = 64, 4, 2, 16
     t = c * hdp

@@ -308,8 +308,7 @@ def _validate_child(trace_out: str) -> None:
     tracer = get_tracer()
     tracer.enabled = True
     cfg = get_config("llama3.2-3b").reduced()
-    mesh = compat.make_mesh((4, 2), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((4, 2), ("data", "model"))
     compat.set_mesh(mesh)
     rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                  remat="none", kv_chunk=64)

@@ -1,162 +1,74 @@
-"""Version-portable JAX runtime layer — the single import point for every
-JAX surface that moved between 0.4.x and ≥0.5.
+"""The single import point for the JAX runtime surfaces the code shares:
+meshes, the ambient mesh, jit shardings, host-offload memory and allocator
+statistics.  The repo targets one JAX release (0.9); this module keeps the
+call sites uniform and lazy.
 
-The rest of the codebase never touches `jax.shard_map`, `jax.make_mesh`'s
-``axis_types`` kwarg, `jax.set_mesh`, `jax.sharding.AxisType`, or host
-memory kinds directly; it imports them from here.  That keeps the full
-stack (ring attention, SSM shard_maps, planner-driven training, dry-run
-lowering) runnable on both the 0.4.x series and the post-0.5 explicit-
-sharding world:
-
-  feature                 jax 0.4.x fallback
-  ----------------------  -------------------------------------------------
-  jax.shard_map           jax.experimental.shard_map.shard_map
-  check_vma=...           check_rep=... (same meaning, renamed)
-  make_mesh(axis_types=)  axis_types dropped (no AxisType enum yet)
-  jax.sharding.AxisType   string-sentinel shim (Auto/Explicit/Manual)
-  jax.set_mesh            legacy global mesh context (Mesh.__enter__)
-  jit(in_shardings=P)     resolve_shardings(): P -> NamedSharding(mesh, P)
-  pinned_host offload     probed; degrades to on-device remat saves
-
-Feature probing is lazy where it would initialize the backend (the dry-run
-sets XLA_FLAGS before first device use; importing this module must never
-touch device state).
+Probing is lazy where it would initialize the backend (the dry-run sets
+XLA_FLAGS before first device use; importing this module must never touch
+device state).
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Optional, Sequence, Tuple
+import os
+from pathlib import Path
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-JAX_VERSION: Tuple[int, ...] = tuple(
-    int(x) for x in jax.__version__.split(".")[:3] if x.isdigit())
-
-HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_USE_MESH = hasattr(jax.sharding, "use_mesh")
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if HAS_TOPLEVEL_SHARD_MAP:
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _check_kwarg_name() -> str:
-    # probe the actual signature rather than keying on where shard_map
-    # lives: the top-level promotion and the check_rep->check_vma rename
-    # landed in different releases
-    try:
-        import inspect
-        params = inspect.signature(_shard_map_impl).parameters
-        if "check_vma" in params:
-            return "check_vma"
-        if "check_rep" in params:
-            return "check_rep"
-    except (ValueError, TypeError):
-        pass
-    return "check_vma" if HAS_TOPLEVEL_SHARD_MAP else "check_rep"
-
-
-_CHECK_KW = _check_kwarg_name()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` with the modern keyword surface on every version.
-
-    ``check_vma`` (varying-manual-axes checking, the post-0.5 name) maps to
-    ``check_rep`` on versions that predate the rename — identical
-    semantics."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check_vma})
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 # ---------------------------------------------------------------------------
 # mesh construction / ambient mesh
 # ---------------------------------------------------------------------------
 
-class _AxisTypeShim:
-    """Stand-in for `jax.sharding.AxisType` on versions without it.  The
-    values are inert sentinels: 0.4.x meshes are implicitly all-Auto, which
-    is exactly what every mesh in this repo requests."""
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = jax.sharding.AxisType if HAS_AXIS_TYPES else _AxisTypeShim
-
-
-def auto_axis_types(n: int) -> tuple:
-    return (AxisType.Auto,) * n
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types: Optional[Sequence[Any]] = None,
               devices=None) -> Mesh:
-    """`jax.make_mesh` that tolerates ``axis_types`` on versions without
-    the kwarg (0.4.x meshes behave as all-Auto already)."""
-    if hasattr(jax, "make_mesh"):
-        kw: dict = {}
-        if devices is not None:
-            kw["devices"] = devices
-        if axis_types is not None and HAS_AXIS_TYPES:
-            kw["axis_types"] = tuple(axis_types)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes), devices=devices)
-    return Mesh(devs, tuple(axis_names))
-
-
-_legacy_mesh_stack: list = []
+    """`jax.make_mesh` with every axis Auto: the code places arrays with
+    NamedShardings and sharding constraints, never explicit-axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def set_mesh(mesh: Mesh) -> Mesh:
     """Install ``mesh`` as the ambient mesh for bare-PartitionSpec
-    resolution (`with_sharding_constraint(x, P(...))` etc.).
-
-    ≥0.5 delegates to `jax.set_mesh`.  0.4.x enters the legacy global mesh
-    context (`with mesh:`) and keeps it open; calling again swaps meshes.
-    """
-    if HAS_SET_MESH:
-        jax.set_mesh(mesh)
-        return mesh
-    while _legacy_mesh_stack:
-        _legacy_mesh_stack.pop().__exit__(None, None, None)
-    mesh.__enter__()
-    _legacy_mesh_stack.append(mesh)
+    resolution (`with_sharding_constraint(x, P(...))` etc.)."""
+    jax.set_mesh(mesh)
     return mesh
 
 
-@contextlib.contextmanager
 def use_mesh(mesh: Mesh):
-    """Scoped variant of `set_mesh` (restores the previous context)."""
-    if HAS_USE_MESH:
-        with jax.sharding.use_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Scoped variant of `set_mesh`: a context that restores the previous
+    ambient mesh on exit."""
+    return jax.set_mesh(mesh)
 
 
 def resolve_shardings(tree, mesh: Mesh):
-    """PartitionSpec pytree -> NamedSharding pytree for `jax.jit`.
-
-    0.4.x `jit` rejects bare PartitionSpecs in in/out_shardings even under
-    a mesh context; NamedSharding works on every version.  None leaves
-    (unspecified shardings) and existing Sharding objects pass through.
-    """
+    """PartitionSpec pytree -> NamedSharding pytree for `jax.jit`, so the
+    shardings do not depend on an ambient mesh.  None leaves (unspecified
+    shardings) and existing Sharding objects pass through."""
     def leaf(s):
         return NamedSharding(mesh, s) if isinstance(s, PartitionSpec) else s
     return jax.tree.map(leaf, tree,
                         is_leaf=lambda x: isinstance(x, PartitionSpec))
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache
+# ---------------------------------------------------------------------------
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache and return its directory.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here; otherwise the cache lives in ``<checkout>/.jax_cache``
+    (a fixed path: the path is part of the cache key).  Called by entry
+    points only, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------
@@ -175,9 +87,8 @@ def memory_kinds() -> set:
 
 def host_offload_memory_kind() -> Optional[str]:
     """The memory kind residuals offload to, or None when the backend has
-    no distinct host memory space (e.g. 0.4.x CPU exposes only
-    ``unpinned_host``, which *is* device memory there — offloading to it
-    would be a no-op, so we report unsupported)."""
+    no distinct host memory space (offloading to device memory would be a
+    no-op, so we report unsupported)."""
     return "pinned_host" if "pinned_host" in memory_kinds() else None
 
 
@@ -187,9 +98,9 @@ def offload_supported() -> bool:
 
 def device_memory_stats(device=None) -> dict:
     """``device.memory_stats()`` with graceful degradation: backends that
-    expose no allocator stats (CPU, some TPU runtimes return None or
-    raise) yield ``{}`` instead of crashing — the bytes ledger's measured
-    HBM watermark simply stays absent there (obs/ledger.py).
+    expose no allocator stats (CPU returns None) yield ``{}`` instead of
+    crashing — the bytes ledger's measured HBM watermark simply stays
+    absent there (obs/ledger.py).
 
     Initializes the backend when ``device`` is None — call lazily, never
     at import time (same discipline as `memory_kinds`)."""
@@ -208,7 +119,7 @@ def offload_policy(names: Sequence[str] = ("resid",)):
     transfer, so plans stay executable everywhere."""
     cp = jax.checkpoint_policies
     kind = host_offload_memory_kind()
-    if kind is not None and hasattr(cp, "save_and_offload_only_these_names"):
+    if kind is not None:
         return cp.save_and_offload_only_these_names(
             names_which_can_be_saved=[],
             names_which_can_be_offloaded=list(names),
@@ -220,11 +131,8 @@ def offload_policy(names: Sequence[str] = ("resid",)):
 # feature registry (conftest skip-with-reason support)
 # ---------------------------------------------------------------------------
 
+# only the probes whose answer differs between the CPU and the TPU backend
 _FEATURES = {
-    "shard_map": lambda: (True, "available via repro.compat"),
-    "axis_types": lambda: (HAS_AXIS_TYPES,
-                           "jax.sharding.AxisType added in jax 0.5"),
-    "set_mesh": lambda: (True, "legacy mesh context substitutes on 0.4.x"),
     "host_offload": lambda: (offload_supported(),
                              "no pinned_host memory on this backend"),
     "memory_stats": lambda: (bool(device_memory_stats()),
@@ -233,8 +141,8 @@ _FEATURES = {
 
 
 def feature_status(name: str) -> Tuple[bool, str]:
-    """(supported, reason-if-not) for a named JAX feature.  Unknown names
-    report unsupported so tests skip loudly rather than crash."""
+    """(supported, reason-if-not) for a named backend feature.  Unknown
+    names report unsupported so tests skip loudly rather than crash."""
     probe = _FEATURES.get(name)
     if probe is None:
         return False, f"unknown JAX feature {name!r}"

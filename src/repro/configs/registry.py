@@ -4,7 +4,8 @@ from __future__ import annotations
 from repro.configs.base import ModelConfig, SHAPES, SHAPE_GRID, ShapeSpec, shape_applicable
 from repro.configs import (qwen2_vl_2b, starcoder2_7b, llama3_2_3b, gemma2_9b,
                            gemma3_12b, qwen3_moe_30b_a3b, deepseek_v2_lite_16b,
-                           rwkv6_7b, musicgen_medium, jamba_1_5_large_398b)
+                           rwkv6_7b, musicgen_medium, jamba_1_5_large_398b,
+                           llama3_2_3b_1chip)
 from repro.configs.paper_models import PAPER_MODELS
 
 ASSIGNED = {
@@ -14,7 +15,12 @@ ASSIGNED = {
         jamba_1_5_large_398b)
 }
 
-ALL_CONFIGS = {**ASSIGNED, **PAPER_MODELS}
+# one chip's share of an assigned model: published widths, cut depth and
+# vocabulary (each module lists its cuts in REDUCED)
+ONE_CHIP = {m.CONFIG.name: m for m in (llama3_2_3b_1chip,)}
+
+ALL_CONFIGS = {**ASSIGNED, **PAPER_MODELS,
+               **{name: m.CONFIG for name, m in ONE_CHIP.items()}}
 
 
 def get_config(name: str) -> ModelConfig:

@@ -106,16 +106,9 @@ def finalize_stats(acc, m, l, dtype) -> jnp.ndarray:
 
 def block_chunked_stats(q, k, v, q_seg, k_seg, q_pos, k_pos, *, scale: float,
                         causal: bool = True, window: int = 0,
-                        softcap: float = 0.0, kv_chunk: int = 1024,
-                        attn_impl: str = "ref"):
+                        softcap: float = 0.0, kv_chunk: int = 1024):
     """Online-softmax stats of q against one KV block, chunking the block's
-    sequence dim for memory safety.  ``attn_impl="pallas"`` dispatches to the
-    Pallas flash kernel (kernels/flash_attention.py)."""
-    if attn_impl == "pallas":
-        from repro.kernels import ops as kernel_ops
-        return kernel_ops.flash_attention_stats(
-            q, k, v, q_seg, k_seg, q_pos, k_pos, scale=scale, causal=causal,
-            window=window, softcap=softcap)
+    sequence dim for memory safety."""
     t, g, hg, _ = q.shape
     s_len = k.shape[0]
     dv = v.shape[-1]

@@ -13,22 +13,35 @@ replaces the per-shard inner loop on TPU.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.transformer import logits_head
 from repro.parallel.sharding import Runtime
 
 
-def token_ce_from_logits(logits, labels, valid, denom, *, impl: str = "ref"):
+def token_ce_from_logits(logits, labels, valid, denom, *, impl: str = "ref",
+                         rt: Optional[Runtime] = None):
     """logits [T, V] (any float dtype), labels [T] int32, valid [T] bool.
+    ``impl="pallas"`` runs the fused kernel on ``rt``'s mesh: a Mosaic
+    kernel is not partitioned automatically, so each HDP rank runs it on
+    its own tokens inside a shard_map (the vocabulary is whole per rank).
 
     Returns (loss, metrics).  loss = Σ_valid nll / denom.
     """
     if impl == "pallas":
         from repro.kernels import ops as kernel_ops
-        nll = kernel_ops.fused_softmax_xent(logits, labels)
+        interpret = kernel_ops.interpret_on(rt.mesh)
+        tok = P(rt.hdp_axes)
+        nll = shard_map(
+            lambda lg, lb: kernel_ops.fused_softmax_xent(lg, lb, interpret),
+            mesh=rt.mesh, in_specs=(P(rt.hdp_axes, None), tok),
+            out_specs=tok, check_vma=False)(logits, labels)
     else:
         lg = logits.astype(jnp.float32)
         m = jnp.max(lg, axis=-1, keepdims=True)
@@ -47,4 +60,4 @@ def token_ce_loss(params, cfg: ModelConfig, rt: Runtime, hidden, labels, seg,
     logits = logits_head(params, cfg, hidden)
     return token_ce_from_logits(logits, labels, seg > 0, denom,
                                 impl="pallas" if rt.attn_impl == "pallas"
-                                else "ref")
+                                else "ref", rt=rt)

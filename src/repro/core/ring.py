@@ -19,9 +19,6 @@ KV block provably cannot attend to any local query (wrong segments, entirely
 in the future, or beyond the sliding window) skips its O(C²) block compute.
 This is a beyond-paper optimization enabled by carrying metadata with the
 ring (see EXPERIMENTS.md §Perf).
-
-All shard_map entry points go through `repro.compat` (not `jax.shard_map`
-directly), so the rings run unchanged on jax 0.4.x and ≥0.5.
 """
 from __future__ import annotations
 
@@ -30,9 +27,9 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import attention as att
 from repro.obs import ledger
 
@@ -116,7 +113,7 @@ def _ring_attention_local(q, kv, q_seg, k_seg, q_pos, k_pos, *,
                           kv_group_index,       # [hpl] int32 or None (kv sharded)
                           scale: float, causal: bool, window: int,
                           softcap: float, kv_chunk: int, block_skip: bool,
-                          attn_impl, unroll: bool = False):
+                          unroll: bool = False):
     """Per-rank body. Local shapes:
         q [C, hpl, D]; kv [C, G(_local), Dk+Dv] fused (or [C, G, Dk] when v
         is a prefix of k — the MLA latent ring ships 576 floats/token
@@ -150,7 +147,7 @@ def _ring_attention_local(q, kv, q_seg, k_seg, q_pos, k_pos, *,
         return att.block_chunked_stats(
             kq, gather(k_blk), gather(v_blk), q_seg, seg_blk, q_pos, pos_blk,
             scale=scale, causal=causal, window=window, softcap=softcap,
-            kv_chunk=kv_chunk, attn_impl=attn_impl)
+            kv_chunk=kv_chunk)
 
     # step 0: local block (always relevant — contains our own diagonal)
     stats = compute_block(kv, k_seg, k_pos)
@@ -247,7 +244,7 @@ def ring_attention(q, k, v, q_seg, k_seg, q_pos, k_pos, *,
             gather=use_group_gather, scale=scale, causal=causal,
             window=window, softcap=softcap, block_q=block_q,
             block_k=block_k, block_skip=block_skip, unroll=unroll,
-            interpret=kernel_ops.INTERPRET)
+            interpret=kernel_ops.interpret_on(mesh))
         ring_fn = kernel_ops.make_ring_flash(ring_cfg)
 
     def body(q_, kv_, qs_, ks_, qp_, kp_):
@@ -265,7 +262,7 @@ def ring_attention(q, k, v, q_seg, k_seg, q_pos, k_pos, *,
             hdp_axes=hdp_axes, composition=composition, kv_split=kv_split,
             kv_group_index=kgi, scale=scale, causal=causal, window=window,
             softcap=softcap, kv_chunk=kv_chunk, block_skip=block_skip,
-            attn_impl="ref", unroll=unroll)
+            unroll=unroll)
 
     fn = shard_map(
         body, mesh=mesh,

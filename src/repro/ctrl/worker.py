@@ -276,8 +276,7 @@ class WorkerAgent:
             rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                          stage_axis="stage", **cfg.get("runtime_kw", {}))
         else:
-            mesh = compat.make_mesh((hdp, tp), ("data", "model"),
-                                    axis_types=compat.auto_axis_types(2))
+            mesh = compat.make_mesh((hdp, tp), ("data", "model"))
             rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                          **cfg.get("runtime_kw", {}))
         compat.set_mesh(mesh)
@@ -404,8 +403,7 @@ class WorkerAgent:
         tp = int(cfg.get("tp", 1))
         need = spec.hdp * tp
         assert need <= len(jax.devices()), (need, len(jax.devices()))
-        mesh = compat.make_mesh((spec.hdp, tp), ("data", "model"),
-                                axis_types=compat.auto_axis_types(2))
+        mesh = compat.make_mesh((spec.hdp, tp), ("data", "model"))
         rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                      **cfg.get("runtime_kw", {}))
         compat.set_mesh(mesh)

@@ -1,22 +1,35 @@
 """jit'd wrappers with custom VJPs around the Pallas kernels.
 
-``INTERPRET`` defaults to True (this container is CPU-only; interpret mode
-executes kernel bodies in Python for correctness validation).  On real TPU
-set ``repro.kernels.ops.INTERPRET = False`` (or the REPRO_PALLAS_COMPILE=1
-env) — BlockSpecs are already MXU/VMEM-shaped.
+Every entry takes ``interpret``: True runs the kernel bodies through the
+Pallas interpreter (the CPU backend has no Mosaic compiler), False compiles
+them for the TPU.  Callers derive it with `interpret_on` from the mesh or
+sharding the computation is placed on; on a TPU a kernel compiles or the
+call fails — nothing falls back to the jnp path.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, Sharding
 
 from repro.kernels import flash_attention as FA
 from repro.kernels import fused_ce as CE
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def interpret_on(where) -> bool:
+    """True iff every device of ``where`` — a Mesh, a Sharding or a
+    sequence of devices — is a CPU device.  Read from the placement
+    in hand, not from `jax.default_backend()`: a compile for a described
+    TPU topology runs in a process whose default backend is the CPU."""
+    if isinstance(where, Mesh):
+        devices = where.devices.flat
+    elif isinstance(where, Sharding):
+        devices = where.device_set
+    else:
+        devices = where
+    return all(d.platform == "cpu" for d in devices)
 
 
 # ---------------------------------------------------------------------------
@@ -24,57 +37,38 @@ INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
 def flash_attention(q, k, v, q_seg, k_seg, q_pos, k_pos,
-                    scale, causal=True, window=0, softcap=0.0,
-                    block_q=256, block_k=512):
+                    scale, causal, window, softcap, block_q, block_k,
+                    interpret):
     """q [G, Hg, T, Dk], k/v [G, S, D*] -> out [G, Hg, T, Dv]."""
     out, _ = FA.flash_attention_fwd(
         q, k, v, q_seg, k_seg, q_pos, k_pos, scale=scale, causal=causal,
         window=window, softcap=softcap, block_q=block_q, block_k=block_k,
-        interpret=INTERPRET)
+        interpret=interpret)
     return out
 
 
 def _fa_fwd(q, k, v, q_seg, k_seg, q_pos, k_pos, scale, causal, window,
-            softcap, block_q, block_k):
+            softcap, block_q, block_k, interpret):
     out, lse = FA.flash_attention_fwd(
         q, k, v, q_seg, k_seg, q_pos, k_pos, scale=scale, causal=causal,
         window=window, softcap=softcap, block_q=block_q, block_k=block_k,
-        interpret=INTERPRET)
+        interpret=interpret)
     return out, (q, k, v, q_seg, k_seg, q_pos, k_pos, out, lse)
 
 
-def _fa_bwd(scale, causal, window, softcap, block_q, block_k, res, do):
+def _fa_bwd(scale, causal, window, softcap, block_q, block_k, interpret,
+            res, do):
     q, k, v, q_seg, k_seg, q_pos, k_pos, out, lse = res
     dq, dk, dv = FA.flash_attention_bwd(
         q, k, v, q_seg, k_seg, q_pos, k_pos, out, lse, do, scale=scale,
         causal=causal, window=window, softcap=softcap, block_q=block_q,
-        block_k=block_k, interpret=INTERPRET)
+        block_k=block_k, interpret=interpret)
     return dq, dk, dv, None, None, None, None
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
-
-
-def flash_attention_stats(q, k, v, q_seg, k_seg, q_pos, k_pos, *, scale,
-                          causal=True, window=0, softcap=0.0):
-    """(acc, m, l) online-softmax stats in core/attention.py's
-    [T, G, Hg, ...] layout — a drop-in for block_chunked_stats so ring
-    steps can merge kernel outputs (forward / inference paths)."""
-    qt = jnp.transpose(q, (1, 2, 0, 3))          # [G, Hg, T, D]
-    kt = jnp.transpose(k, (1, 0, 2))             # [G, S, Dk]
-    vt = jnp.transpose(v, (1, 0, 2))
-    out, lse = FA.flash_attention_fwd(
-        qt, kt, vt, q_seg, k_seg, q_pos, k_pos, scale=scale, causal=causal,
-        window=window, softcap=softcap, interpret=INTERPRET)
-    # stats with m = lse, l = 1 merge identically to the jnp path:
-    # merge uses acc·e^{m-M}: acc must be the UNnormalized numerator with
-    # its own lse base: acc = out · l where l = e^{lse - m}=1 under m=lse.
-    m = jnp.transpose(lse, (2, 0, 1))            # [T, G, Hg]
-    acc = jnp.transpose(out, (2, 0, 1, 3)).astype(jnp.float32)
-    l = jnp.where(m > FA.NEG_INF / 2, 1.0, 0.0)
-    return acc, m, l
 
 
 # ---------------------------------------------------------------------------
@@ -121,22 +115,22 @@ def make_ring_flash(cfg):
 # fused softmax cross-entropy, differentiable
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=())
-def fused_softmax_xent(logits, labels):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def fused_softmax_xent(logits, labels, interpret):
     """logits [T, V] (bf16/f32), labels [T] int32 -> nll [T] fp32."""
-    nll, _, _ = CE.fused_ce_fwd(logits, labels, interpret=INTERPRET)
+    nll, _ = CE.fused_ce_fwd(logits, labels, interpret=interpret)
     return nll
 
 
-def _ce_fwd(logits, labels):
-    nll, lse, _ = CE.fused_ce_fwd(logits, labels, interpret=INTERPRET)
+def _ce_fwd(logits, labels, interpret):
+    nll, lse = CE.fused_ce_fwd(logits, labels, interpret=interpret)
     return nll, (logits, labels, lse)
 
 
-def _ce_bwd(res, g):
+def _ce_bwd(interpret, res, g):
     logits, labels, lse = res
     dlogits = CE.fused_ce_bwd(logits, labels, lse, g.astype(jnp.float32),
-                              interpret=INTERPRET)
+                              interpret=interpret)
     return dlogits, None
 
 

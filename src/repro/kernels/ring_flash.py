@@ -72,6 +72,7 @@ class RingConfig:
     kv_split: Tuple[int, int, int]            # (dk, v_off, dv)
     gather: bool
     scale: float
+    interpret: bool                           # kernels/ops.interpret_on
     causal: bool = True
     window: int = 0
     softcap: float = 0.0
@@ -79,7 +80,6 @@ class RingConfig:
     block_k: int = 512
     block_skip: bool = True
     unroll: bool = False
-    interpret: bool = True
 
     @property
     def steps(self) -> int:

@@ -1,4 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction: the local H×M mesh of ``--mesh HxM`` over the
+devices at hand, and the production meshes the dry-run lowers onto.
+
+Local:      (H, M) = ("data", "model") over exactly `jax.devices()`.
 
 Single pod: (16, 16) = ("data", "model") — 256 chips.
 Multi-pod:  (2, 16, 16) = ("pod", "data", "model") — 512 chips.
@@ -17,9 +20,23 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
+
 from repro import compat
 
 NON_HDP_AXES = ("model", "stage")
+
+
+def make_local_mesh(spec: str):
+    """``--mesh HxM``: H-way HDP × M-way tensor parallelism over every
+    device `jax.devices()` reports.  Fails unless H·M is that count."""
+    dims = tuple(int(x) for x in spec.lower().split("x"))
+    devices = jax.devices()
+    if len(dims) != 2 or dims[0] * dims[1] != len(devices):
+        raise ValueError(
+            f"--mesh {spec} needs H×M = {len(devices)} devices "
+            f"({devices[0].platform} x{len(devices)} present)")
+    return compat.make_mesh(dims, ("data", "model"), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, num_stages: int = 1):
@@ -33,15 +50,13 @@ def make_production_mesh(*, multi_pod: bool = False, num_stages: int = 1):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=compat.auto_axis_types(len(axes)))
+    return compat.make_mesh(shape, axes)
 
 
 def make_pipeline_mesh(num_stages: int, hdp: int, tp: int = 1):
     """Small-scale pipelined mesh (examples / CPU tests): stage × data ×
     model over num_stages · hdp · tp devices."""
-    return compat.make_mesh((num_stages, hdp, tp), ("stage", "data", "model"),
-                            axis_types=compat.auto_axis_types(3))
+    return compat.make_mesh((num_stages, hdp, tp), ("stage", "data", "model"))
 
 
 def hdp_axes_of(mesh) -> Tuple[str, ...]:

@@ -1,8 +1,9 @@
-"""Production launcher: ``python -m repro.launch.train --arch <id> ...``.
+"""Training launcher: ``python -m repro.launch.train --arch <id> ...``.
 
-On the CPU container this runs reduced configs end-to-end; on TPU pods the
-same entry point takes ``--mesh 16x16`` / ``--mesh 2x16x16`` and full-size
-configs (jax.distributed initialization is the standard pod runtime).
+On the CPU it runs reduced configs end to end (``--reduced``); on a TPU
+host the same entry point takes ``--mesh HxM`` over the host's chips and a
+one-chip-share config such as ``llama3.2-3b-1chip``.  `make_trainer` is
+the single-process path; ``chip_smoke.py`` drives it too.
 """
 from __future__ import annotations
 
@@ -13,9 +14,9 @@ from repro import compat
 from repro.configs.registry import get_config
 from repro.data.distribution import DISTRIBUTIONS, LengthDistribution
 from repro.data.loader import GlobalScheduler, SyntheticDataset
-from repro.launch.mesh import hdp_axes_of, make_production_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.optim.adamw import AdamWConfig
-from repro.parallel.sharding import Runtime, single_device_runtime
+from repro.parallel.sharding import Runtime
 from repro.train.trainer import Trainer, TrainerConfig
 
 
@@ -34,6 +35,29 @@ def _resolve_config(args):
     ds = SyntheticDataset(dist, cfg.vocab_size, args.tokens_per_step,
                           args.context)
     return cfg, ds
+
+
+def make_trainer(args) -> Trainer:
+    """The single-process path: config, ``--mesh HxM`` mesh over the
+    devices at hand (installed as the ambient mesh), scheduler and
+    trainer.  The caller iterates ``trainer.run(n)`` and must call
+    ``trainer.sched.stop()`` when done."""
+    cfg, ds = _resolve_config(args)
+    mesh = make_local_mesh(args.mesh)
+    rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model")
+    compat.set_mesh(rt.mesh)
+    sched = GlobalScheduler(ds, cfg, capacity=args.capacity,
+                            hdp=rt.hdp_size, strategy=args.strategy,
+                            use_offload=False, lookahead=args.lookahead,
+                            sched_async=args.sched_async)
+    return Trainer(cfg, rt,
+                   AdamWConfig(lr=args.lr, total_steps=args.steps),
+                   sched, TrainerConfig(capacity=args.capacity,
+                                        ckpt_dir=args.ckpt_dir,
+                                        strategy=args.strategy,
+                                        attn_impl=args.attn_impl,
+                                        max_round_waves=args.max_round_waves,
+                                        sched_async=args.sched_async))
 
 
 def _run_ctrl(args):
@@ -99,7 +123,7 @@ def _analyze_trace_dir(trace_dir):
     return attribution, mfu_goodput(merged, attribution)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -113,13 +137,15 @@ def main():
     ap.add_argument("--strategy", default="balance",
                     choices=["static", "naive", "balance"])
     ap.add_argument("--mesh", default="1x1",
-                    help="e.g. 16x16 or 2x16x16 (production)")
+                    help="HxM: H-way HDP x M-way tensor parallel over all "
+                         "local devices (H*M must equal their count); "
+                         "--ctrl: each worker's geometry")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--attn-impl", default=None, choices=["ref", "pallas"],
                     help="attention backend: jnp oracle ring (ref) or the "
-                         "Pallas ring-flash engine (pallas; interpret-mode "
-                         "on CPU unless REPRO_PALLAS_COMPILE=1)")
+                         "Pallas ring-flash engine (pallas; compiled on a "
+                         "TPU, interpreted on CPU devices)")
     ap.add_argument("--max-round-waves", type=int, default=0,
                     help="pipelined executor: cap waves per round (0 = "
                          "uncapped) to bound in-flight activation memory")
@@ -170,7 +196,12 @@ def main():
                     help="append one JSONL metrics record per step here")
     ap.add_argument("--report", action="store_true",
                     help="print the observability dashboard on exit")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    compat.enable_compile_cache()
 
     from repro.obs import (configure as obs_configure, get_metrics,
                            get_recorder, get_tracer, render_report)
@@ -209,29 +240,7 @@ def main():
                     if ctl is not None else None,
                     title="controller"), flush=True)
 
-    cfg, ds = _resolve_config(args)
-
-    dims = tuple(int(x) for x in args.mesh.split("x"))
-    if dims == (1, 1):
-        rt = single_device_runtime()
-    else:
-        mesh = make_production_mesh(multi_pod=len(dims) == 3)
-        rt = Runtime(mesh=mesh, hdp_axes=hdp_axes_of(mesh),
-                     model_axis="model")
-    compat.set_mesh(rt.mesh)
-
-    sched = GlobalScheduler(ds, cfg, capacity=args.capacity,
-                            hdp=rt.hdp_size, strategy=args.strategy,
-                            use_offload=False, lookahead=args.lookahead,
-                            sched_async=args.sched_async)
-    trainer = Trainer(cfg, rt,
-                      AdamWConfig(lr=args.lr, total_steps=args.steps),
-                      sched, TrainerConfig(capacity=args.capacity,
-                                           ckpt_dir=args.ckpt_dir,
-                                           strategy=args.strategy,
-                                           attn_impl=args.attn_impl,
-                                           max_round_waves=args.max_round_waves,
-                                           sched_async=args.sched_async))
+    trainer = make_trainer(args)
     if args.ckpt_dir and trainer.resume_if_possible():
         print(f"resumed at step {trainer.step}")
     try:
@@ -240,7 +249,7 @@ def main():
                   f"waves {rec['waves']} wall {rec['wall_s']:.1f}s",
                   flush=True)
     finally:
-        sched.stop()      # the planner thread must not outlive the loop
+        trainer.sched.stop()  # the planner thread must not outlive the loop
         if args.trace_out:
             get_tracer().to_chrome(args.trace_out)
             print(f"trace -> {args.trace_out}", flush=True)

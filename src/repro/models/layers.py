@@ -184,5 +184,9 @@ def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float = 1.0) -> jnp
     return (jax.random.normal(key, (in_dim, out_dim), jnp.float32) * std).astype(dtype)
 
 
+EMBED_INIT_STD = 0.02
+
+
 def embed_init(key, vocab: int, d: int, dtype) -> jnp.ndarray:
-    return (jax.random.normal(key, (vocab, d), jnp.float32) * 0.02).astype(dtype)
+    return (jax.random.normal(key, (vocab, d), jnp.float32)
+            * EMBED_INIT_STD).astype(dtype)

@@ -12,9 +12,8 @@ apply a correction pass — see DESIGN.md §5.
 Segment handling: decay is forced to 0 at segment starts (history drop) and
 to 1 on padding (transparent); the causal conv masks cross-segment taps.
 
-The per-rank sweep is pure jnp: `models/transformer.py` wraps it in the
-version-portable `repro.compat.shard_map` (not `jax.shard_map`), so this
-module needs no JAX-version gating of its own.
+The per-rank sweep is pure jnp: `models/transformer.py` wraps it in
+`jax.shard_map` over the HDP axes.
 """
 from __future__ import annotations
 

@@ -23,9 +23,8 @@ Under HDP, a sequence sharded over a rank group composes the per-rank
 ring-attention does not apply to attention-free mixers; token-balanced
 scheduling still does).
 
-The per-rank sweep is pure jnp: `models/transformer.py` wraps it in the
-version-portable `repro.compat.shard_map` (not `jax.shard_map`), so this
-module needs no JAX-version gating of its own.
+The per-rank sweep is pure jnp: `models/transformer.py` wraps it in
+`jax.shard_map` over the HDP axes.
 """
 from __future__ import annotations
 

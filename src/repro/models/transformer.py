@@ -20,10 +20,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import offload_policy, shard_map
+from repro.compat import offload_policy
 from repro.configs.base import ModelConfig
 from repro.core import ring as R
 from repro.obs import ledger

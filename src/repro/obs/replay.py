@@ -201,8 +201,7 @@ def _build_trainer(man: dict, provs: Dict[int, dict]):
         rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                      stage_axis="stage", **extra)
     else:
-        mesh = compat.make_mesh((hdp, tp), ("data", "model"),
-                                axis_types=compat.auto_axis_types(2))
+        mesh = compat.make_mesh((hdp, tp), ("data", "model"))
         rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                      **extra)
     compat.set_mesh(mesh)

@@ -48,7 +48,7 @@ def schedule_lr(cfg: AdamWConfig, step):
 
 def init_state(params) -> dict:
     f32 = lambda p: p.astype(jnp.float32)          # noqa: E731
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)  # noqa: E731
+    zeros = lambda p: jnp.zeros_like(p, jnp.float32)  # noqa: E731
     return {
         "step": jnp.zeros((), jnp.int32),
         "master": jax.tree.map(f32, params),

@@ -70,8 +70,7 @@ class Runtime:
 
 def single_device_runtime(**kw) -> Runtime:
     """CPU smoke-test runtime: a 1×1 mesh."""
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     return Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
                    composition=(1,), **kw)
 

@@ -10,8 +10,7 @@ DP).  On a mesh with a stage axis (Runtime.num_stages > 1) the wave queue
 instead runs through the pipelined executor: waves group into rounds of
 like (composition, c_mult, offload) and each round executes the wavefront
 microbatch schedule of parallel/pipeline.py, each wave one pipeline
-microbatch (PP-Balance pairs with this path via TrainerConfig.mode="pp").  Version-sensitive JAX surfaces (shard_map, meshes, host offload) are
-reached via `repro.compat`, so the loop runs on jax 0.4.x and ≥0.5.
+microbatch (PP-Balance pairs with this path via TrainerConfig.mode="pp").
 
 Fault tolerance: periodic async checkpoints (atomic + hash-verified) with
 auto-resume; ``resize()`` re-plans for a different HDP size (parameters are
@@ -46,7 +45,8 @@ from repro.parallel.pipeline import (assert_pipeline_ready,
                                      pipeline_rounds,
                                      pipeline_schedule_stats,
                                      rounds_splitter)
-from repro.parallel.sharding import Runtime
+from repro.parallel.sharding import (Runtime, params_pspecs,
+                                     shardings_from_pspecs)
 from repro.train.train_step import make_accum_steps
 
 
@@ -110,11 +110,22 @@ class Trainer:
         self.offload_ok = tcfg.use_offload and compat.offload_supported()
         self._align_offload(scheduler)
         self.loader = WaveMaterializer(scheduler.ds, cfg, tcfg.capacity)
-        self.params = init_params(jax.random.PRNGKey(seed), cfg, rt)
+        params = init_params(jax.random.PRNGKey(seed), cfg, rt)
+        # commit the parameters to the mesh by the sharding rules, so the
+        # optimizer state is born there too: left on the first device,
+        # every call would reshard them and the first apply would hold a
+        # second copy of the AdamW state there
+        self.params = jax.device_put(params, shardings_from_pspecs(
+            params_pspecs(params, cfg, rt), rt.mesh))
+        del params
         self.opt_state = adamw.init_state(self.params)
         self.step = 0
         self.grad_step, self.apply_step = make_accum_steps(
             cfg, rt, opt_cfg, guard=tcfg.numerics_guard)
+        # the f32 gradient accumulator is donated wherever it is consumed:
+        # a second live copy of it would not fit beside the optimizer
+        # state of a one-chip-share model (configs/llama3_2_3b_1chip.py)
+        self._apply_jit = jax.jit(self.apply_step, donate_argnums=(2,))
         self.pipelined = rt.num_stages > 1
         if self.pipelined:
             assert_pipeline_ready(cfg, rt)
@@ -246,7 +257,8 @@ class Trainer:
         if fresh:
             rt_wave = self._wave_rt(composition, offload_ratio)
             self._exec_cache[key] = jax.jit(
-                lambda p, g, b: self.grad_step(p, g, b, rt_wave))
+                lambda p, g, b: self.grad_step(p, g, b, rt_wave),
+                donate_argnums=(1,))
         return self._exec_cache[key], fresh
 
     def _round_fn(self, composition, c_mult, offload_ratio, n_waves: int):
@@ -260,7 +272,8 @@ class Trainer:
         if fresh:
             rt_round = self._wave_rt(composition, offload_ratio)
             self._exec_cache[key] = jax.jit(
-                lambda p, g, b: self.pipeline_grad_step(p, g, b, rt_round))
+                lambda p, g, b: self.pipeline_grad_step(p, g, b, rt_round),
+                donate_argnums=(1,))
         return self._exec_cache[key], fresh
 
     def resume_if_possible(self):
@@ -557,7 +570,7 @@ class Trainer:
             for _ in wave_iter:         # drain the prefetch epilogue so
                 pass                    # producer errors still surface
         with tr.span("apply", step=self.step):
-            self.params, self.opt_state, om = jax.jit(self.apply_step)(
+            self.params, self.opt_state, om = self._apply_jit(
                 self.params, self.opt_state, grads)
             # ONE host fetch for the whole fused sentinel summary
             # (grad_norm + per-group norms + non-finite count + applied
@@ -583,6 +596,7 @@ class Trainer:
         self.step += 1
         rec = {"step": self.step, "loss": float(np.sum(losses)),
                "waves": len(plan.waves),
+               "compositions": [tuple(w.composition) for w in plan.waves],
                "bubble_frac": plan.stats["bubble_frac"],
                "grad_norm": float(om["grad_norm"]),
                # wall_s on the monotonic clock (same timeline as every

@@ -4,22 +4,13 @@
   separate process; distributed tests spawn subprocesses with their own
   XLA_FLAGS).  CI may export XLA_FLAGS=--xla_force_host_platform_device_count=8
   — the smoke tests only ever use device 0, so that is harmless.
-* When `hypothesis` is not installed, a deterministic in-repo fallback
-  (tests/_propshim.py) is registered under the same import name so the
-  property tests still run instead of erroring at collection.
-* Tests that need a JAX feature the running version genuinely lacks skip
-  with a reason (via `repro.compat.feature_status`) instead of hard-erroring:
-  mark them ``@pytest.mark.jax_feature("host_offload")`` etc.
+* Tests that need a backend feature the CPU lacks skip with a reason (via
+  `repro.compat.feature_status`) instead of hard-erroring: mark them
+  ``@pytest.mark.jax_feature("host_offload")`` etc.
 """
 import os
 
 os.environ.setdefault("XLA_FLAGS", "")
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import _propshim
-    _propshim.install()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -31,8 +22,8 @@ from repro.parallel.sharding import single_device_runtime  # noqa: E402
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "jax_feature(name): skip when the running JAX lacks the feature "
-        "(names: shard_map, axis_types, set_mesh, host_offload)")
+        "jax_feature(name): skip when the backend lacks the feature "
+        "(names: host_offload, memory_stats)")
 
 
 def pytest_runtest_setup(item):
@@ -48,13 +39,6 @@ def pytest_runtest_setup(item):
 
 @pytest.fixture(scope="session")
 def rt1():
-    try:
-        rt = single_device_runtime(remat="none")
-    except (AttributeError, NotImplementedError) as e:
-        # AttributeError = a JAX surface genuinely absent from this
-        # version (compat needs extending) -> skip with reason; anything
-        # else, including TypeError from a bad refactor, errors loudly
-        pytest.skip(f"single-device runtime unavailable on jax "
-                    f"{jax.__version__}: {e!r}")
+    rt = single_device_runtime(remat="none")
     compat.set_mesh(rt.mesh)
     return rt

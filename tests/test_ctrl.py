@@ -191,8 +191,7 @@ assert len(hist) == STEPS, hist
 assert all(r["workers"] == 2 for r in hist), hist
 
 # single-process reference on the same data/spec/geometry
-mesh = compat.make_mesh((HDP, 1), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((HDP, 1), ("data", "model"))
 compat.set_mesh(mesh)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model", **RT_KW)
 sched = GlobalScheduler(make_ds(), cfg, capacity=CAP, hdp=HDP,
@@ -339,8 +338,7 @@ assert post[-1]["step"] == STEPS, post
 # size, restored from the SAME checkpoint the cluster resumed from, must
 # reproduce the post-resume trajectory bit-for-bit
 resume = post[0]["step"] - 1
-mesh = compat.make_mesh((new_hdp, 1), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((new_hdp, 1), ("data", "model"))
 compat.set_mesh(mesh)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model", **RT_KW)
 sched = GlobalScheduler(make_ds(), cfg, capacity=CAP, hdp=new_hdp,
@@ -415,8 +413,7 @@ cli.close()
 cluster.shutdown()
 
 # the routed results match a local engine on the same params (same seed)
-mesh = compat.make_mesh((1, 1), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((1, 1), ("data", "model"))
 compat.set_mesh(mesh)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model", **RT_KW)
 params = init_params(__import__("jax").random.PRNGKey(0), cfg, rt)

@@ -13,8 +13,7 @@ from repro import compat
 from repro.core.ring import ring_attention
 from repro.core.attention import attention_dense_oracle
 
-mesh = compat.make_mesh((4,2), ("data","model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4,2), ("data","model"))
 compat.set_mesh(mesh)
 C, R = 16, 4; T = C*R
 H, G, D = 4, 2, 8
@@ -61,8 +60,7 @@ from repro.core.loss import token_ce_loss
 
 # sharded ring-grad == single-device grad (HDP distribution is exact)
 cfg = get_config("llama3.2-3b").reduced()
-mesh = compat.make_mesh((4,2), ("data","model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4,2), ("data","model"))
 compat.set_mesh(mesh)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
              composition=(2,2), remat="none", kv_chunk=16)

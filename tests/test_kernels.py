@@ -35,7 +35,8 @@ def test_flash_attention_shapes_dtypes(g, hg, t, s, dk, dv, dtype):
     q_seg, q_pos = _meta(rng, t, 3)
     k_seg, k_pos = _meta(rng, s, 3)
     out = ops.flash_attention(q, k, v, q_seg, k_seg, q_pos, k_pos,
-                              dk ** -0.5, True, 0, 0.0, 32, 32)
+                              dk ** -0.5, True, 0, 0.0, 32, 32,
+                              ops.interpret_on(q.sharding))
     oracle = ref.flash_attention_ref(q, k, v, q_seg, k_seg, q_pos, k_pos,
                                      scale=dk ** -0.5)
     tol = 2e-2 if dtype == jnp.bfloat16 else 5e-5
@@ -55,9 +56,12 @@ def test_flash_attention_grads(window, softcap):
     q_seg, q_pos = _meta(rng, t, 2)
     k_seg, k_pos = _meta(rng, s, 2)
 
+    interpret = ops.interpret_on(q.sharding)
+
     def f(q, k, v):
         return (ops.flash_attention(q, k, v, q_seg, k_seg, q_pos, k_pos,
-                                    0.2, True, window, softcap, 32, 32) ** 2).sum()
+                                    0.2, True, window, softcap, 32, 32,
+                                    interpret) ** 2).sum()
 
     def fr(q, k, v):
         o = ref.flash_attention_ref(q, k, v, q_seg, k_seg, q_pos, k_pos,
@@ -77,14 +81,51 @@ def test_fused_ce_sweep(t, v, dtype):
     rng = np.random.RandomState(t)
     logits = jnp.array(rng.randn(t, v) * 3, dtype)
     labels = jnp.array(rng.randint(0, v, t), jnp.int32)
-    nll = ops.fused_softmax_xent(logits, labels)
+    interpret = ops.interpret_on(logits.sharding)
+    nll = ops.fused_softmax_xent(logits, labels, interpret)
     nll_r, _ = ref.fused_ce_ref(logits, labels)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(nll), np.asarray(nll_r), atol=tol,
                                rtol=tol)
     g = jnp.array(rng.randn(t), jnp.float32)
-    d1 = jax.grad(lambda lg: (ops.fused_softmax_xent(lg, labels) * g).sum())(
-        logits)
+    d1 = jax.grad(lambda lg: (ops.fused_softmax_xent(lg, labels, interpret)
+                              * g).sum())(logits)
     d2 = ref.fused_ce_grad_ref(logits, labels, g)
     np.testing.assert_allclose(np.asarray(d1, np.float32),
                                np.asarray(d2, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("v,block_v", [(2500, 2048), (1000, 384)])
+def test_fused_ce_ragged_vocab(v, block_v):
+    """A vocabulary that does not divide into panels (Llama's 128,256 does
+    not divide by 2048): the ragged last panel is masked out of the forward
+    softmax and its columns of dlogits are written.  Reference: the jnp
+    token-level loss of core/loss.py and its gradient."""
+    from repro.core.loss import token_ce_from_logits
+    from repro.kernels import fused_ce as CE
+    rng = np.random.RandomState(v)
+    t = 32
+    logits = jnp.array(rng.randn(t, v) * 3, jnp.float32)
+    labels = jnp.array(rng.randint(0, v, t), jnp.int32)
+    labels = labels.at[0].set(v - 1)            # a label in the ragged tail
+    valid = jnp.ones(t, bool)
+    interpret = ops.interpret_on(logits.sharding)
+
+    def ref_loss(lg):
+        return token_ce_from_logits(lg, labels, valid, float(t))[0]
+
+    nll, lse = CE.fused_ce_fwd(logits, labels, interpret=interpret,
+                               block_v=block_v)
+    np.testing.assert_allclose(float(nll.sum()) / t, float(ref_loss(logits)),
+                               rtol=1e-5)
+    d_ref = np.asarray(jax.grad(ref_loss)(logits))
+    g = jnp.full((t,), 1.0 / t, jnp.float32)
+    d_kernel = CE.fused_ce_bwd(logits, labels, lse, g, interpret=interpret,
+                               block_v=block_v)
+    np.testing.assert_allclose(np.asarray(d_kernel), d_ref, atol=1e-6,
+                               rtol=1e-4)
+    # the production wrapper (default panels) through jax.grad
+    d_ops = jax.grad(lambda lg: (ops.fused_softmax_xent(lg, labels, interpret)
+                                 * g).sum())(logits)
+    np.testing.assert_allclose(np.asarray(d_ops), d_ref, atol=1e-6,
+                               rtol=1e-4)

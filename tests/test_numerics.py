@@ -328,8 +328,7 @@ from repro.train.trainer import Trainer, TrainerConfig
 
 ckpt_dir = sys.argv[1]
 cfg = get_config("llama3.2-3b").reduced()
-mesh = compat.make_mesh((8, 1), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((8, 1), ("data", "model"))
 compat.set_mesh(mesh)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
              remat="none", kv_chunk=64)

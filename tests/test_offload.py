@@ -95,7 +95,7 @@ def test_ratio_for_d_consistency(s, d):
 def test_offload_remat_executes_on_host_memory():
     """Execution side of Eq. 3: a forward under remat="offload" must
     compile and run when the backend exposes a pinned_host memory space
-    (skips with a reason elsewhere — e.g. 0.4.x CPU has none)."""
+    (skips with a reason elsewhere — the CPU backend has none)."""
     import dataclasses as dc
 
     import jax

@@ -50,8 +50,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro import compat
 from repro.core.ring import ring_attention
 
-mesh = compat.make_mesh((4,2), ("data","model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4,2), ("data","model"))
 compat.set_mesh(mesh)
 C, R = 16, 4; T = C*R
 H, G, D = 4, 2, 8
@@ -115,8 +114,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro import compat
 from repro.core.ring import ring_attention
 
-mesh = compat.make_mesh((4,2), ("data","model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4,2), ("data","model"))
 compat.set_mesh(mesh)
 C, R = 16, 4; T = C*R
 H, G, D = 4, 2, 8
@@ -182,8 +180,7 @@ from repro.core.loss import token_ce_loss
 # model-level loss + grad parity on a Gemma-style config (softcap + local
 # window layers), pallas ring engine vs jnp oracle ring, composition (2,2)
 cfg = dc.replace(get_config("gemma2-9b").reduced(), window=9)
-mesh = compat.make_mesh((4,2), ("data","model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4,2), ("data","model"))
 compat.set_mesh(mesh)
 def make_rt(impl):
     return Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",

@@ -262,8 +262,7 @@ from repro.parallel.sharding import Runtime
 from repro.train.trainer import Trainer, TrainerConfig
 
 cfg = get_config("llama3.2-3b").reduced()
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4, 2), ("data", "model"))
 compat.set_mesh(mesh)
 DIST = LengthDistribution("tiny", 4.5, 0.8, 0.1, 1.5, 256)
 
@@ -308,8 +307,7 @@ from repro.parallel.sharding import Runtime
 from repro.train.trainer import Trainer, TrainerConfig
 
 cfg = get_config("llama3.2-3b").reduced()
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                        axis_types=compat.auto_axis_types(2))
+mesh = compat.make_mesh((4, 2), ("data", "model"))
 compat.set_mesh(mesh)
 DIST = LengthDistribution("tiny", 4.5, 0.8, 0.1, 1.5, 256)
 rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
