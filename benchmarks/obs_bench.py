@@ -8,8 +8,9 @@ Four measurements:
   of an untraced CPU training step: (events-per-step x per-span cost +
   ledger-records-per-step x per-record cost + the A/B'd fused-sentinel
   apply delta) / median clean (no-compile) untraced step wall.
-  The ledger leg runs on the real trainer too — the ledger rides the
-  tracer, so record counts come from the same traced steps.  Gate
+  The ledger leg runs on the real trainer too — the traced leg turns
+  the ledger on beside the tracer, so record counts come from the same
+  traced steps.  Gate
   (CI): combined overhead < 2% of a step AND the traced leg produced
   ledger records.  The disabled paths must stay effectively free (span:
   one attribute check returning a shared no-op singleton; ledger trace
@@ -30,7 +31,7 @@ Four measurements:
 
   - a CLEAN run — the merged cross-process trace must validate, every
     (step x lane) time attribution must close within 5% of its step
-    wall, MFU/goodput must price, and the online anomaly detector must
+    wall, goodput must read above 0, and the online anomaly detector must
     emit ZERO advisories (false-positive gate — numerics advisories
     count too);
   - an injected ``slow_ranks={1: 3.0}`` straggler run — a straggler
@@ -136,13 +137,17 @@ def tracing_overhead(steps: int = 5) -> dict:
                 break
         return float(np.median(clean or dirty)), ran
 
+    from repro.obs import ledger as ledger_mod
     prev = get_tracer()
     tracer = Tracer(enabled=True)
+    ledger_was = ledger_mod.ledger_enabled()
     try:
         set_tracer(tracer)
+        ledger_mod.set_ledger_enabled(True)
         on, ran_on = measure(steps)
         n_events = len(tracer.snapshot())
         tracer.enabled = False
+        ledger_mod.set_ledger_enabled(ledger_was)
         off, _ = measure(steps)
 
         # tight-loop per-span cost, enabled and (the default) disabled
@@ -161,13 +166,11 @@ def tracing_overhead(steps: int = 5) -> dict:
                 pass
         span_off_s = (time.perf_counter() - t0) / n_loop
 
-        # bytes-ledger cost.  The ledger rode the traced leg above
-        # (Trainer._ensure_ledger activates it whenever the tracer is
-        # on), so the record count comes from the real trainer; its
+        # bytes-ledger cost.  The ledger was on for the traced leg
+        # above, so the record count comes from the real trainer; its
         # per-record host cost comes from a tight loop on a standalone
         # Ledger, and the disabled trace-site guard (`tally_active`,
         # one thread-local read) is priced like the disabled span.
-        from repro.obs import ledger as ledger_mod
         ledger_records = tr.ledger.summary()["n"] if tr.ledger else 0
         led = ledger_mod.Ledger(tr.cfg, capacity=256, hdp=1,
                                 max_records=64)
@@ -185,6 +188,7 @@ def tracing_overhead(steps: int = 5) -> dict:
         tally_off_s = (time.perf_counter() - t0) / n_loop
     finally:
         set_tracer(prev)
+        ledger_mod.set_ledger_enabled(ledger_was)
 
     # numerics-sentinel cost (obs/numerics.py).  The fused in-graph
     # summary rides the once-per-step optimizer apply; A/B the jitted
@@ -445,11 +449,10 @@ def _run_cluster_child(trace_dir: str, slow: bool,
 
 
 def _merge_dir(trace_dir: str):
-    """Merge + validate + attribute + price every per-process trace a
-    cluster child left in ``trace_dir``."""
+    """Merge + validate + attribute + read the goodput of every
+    per-process trace a cluster child left in ``trace_dir``."""
     from repro.obs import validate_chrome_trace
-    from repro.obs.analyze import (attribute_steps, merge_traces,
-                                   mfu_goodput)
+    from repro.obs.analyze import attribute_steps, goodput, merge_traces
     paths = sorted(p for p in
                    glob.glob(os.path.join(trace_dir, "trace_*.json"))
                    if "merged" not in os.path.basename(p))
@@ -460,7 +463,7 @@ def _merge_dir(trace_dir: str):
     ok, problems = validate_chrome_trace(merged)
     attribution = attribute_steps(merged)
     return (paths, merged, ok, problems, attribution,
-            mfu_goodput(merged, attribution))
+            goodput(merged, attribution))
 
 
 def cluster_analysis(base_dir: str = None) -> dict:
@@ -470,7 +473,7 @@ def cluster_analysis(base_dir: str = None) -> dict:
     # -- clean run: trace pipeline + zero-false-positive gate ----------
     clean_dir = os.path.join(base_dir, "cluster_clean")
     clean = _run_cluster_child(clean_dir, slow=False)
-    paths, _merged, ok, problems, attribution, mfu = _merge_dir(clean_dir)
+    paths, _merged, ok, problems, attribution, good = _merge_dir(clean_dir)
     worst = max((abs(r["check"] - 1.0) for r in attribution),
                 default=None)
     lanes = len({(r["pid"], r["tid"]) for r in attribution})
@@ -479,17 +482,15 @@ def cluster_analysis(base_dir: str = None) -> dict:
                  if a.get("kind") == "numerics"])
     clean_ok = bool(ok and n_fp == 0 and worst is not None
                     and worst <= ATTR_GATE and lanes >= 3
-                    and (mfu.get("mfu") or 0) > 0
-                    and (mfu.get("goodput") or 0) > 0)
+                    and (good.get("goodput") or 0) > 0)
     out["clean"] = {
         "n_processes": len(paths), "trace_valid": ok,
         "problems": problems[:4], "lanes": lanes,
         "attr_worst": round(worst, 5) if worst is not None else None,
         "attr_gate": ATTR_GATE, "false_positives": n_fp,
         "numerics_advisories": n_num,     # subset of false_positives
-        "mfu": mfu.get("mfu"), "goodput": mfu.get("goodput"),
-        "tokens_per_s": mfu.get("tokens_per_s"),
-        "waves_priced": mfu.get("n_waves"),
+        "goodput": good.get("goodput"),
+        "steps": good.get("n_steps"),
         "anomaly": clean["anomaly"], "gate_ok": clean_ok}
 
     # -- injected straggler: bounded-wave mid-step detection gate ------
@@ -523,7 +524,7 @@ def cluster_analysis(base_dir: str = None) -> dict:
     # clean run's merged trace plus the straggler run's advisories
     from repro.obs.report import render_report
     with open(os.path.join(base_dir, "cluster_report.txt"), "w") as f:
-        f.write(render_report(attribution=attribution, mfu=mfu,
+        f.write(render_report(attribution=attribution, goodput=good,
                               advisories=slow["advisories"],
                               title="obs_bench cluster analysis"))
         f.write("\n")
@@ -629,7 +630,6 @@ def rows_from(snap: dict) -> list:
         rows.append(("obs.cluster_clean", 0.0,
                      f"fp={cl['clean']['false_positives']} "
                      f"attr_worst={cl['clean']['attr_worst']} "
-                     f"mfu={cl['clean']['mfu']} "
                      f"goodput={cl['clean']['goodput']}"))
         rows.append(("obs.cluster_straggler",
                      float(cl["straggler"]["detect_waves"] or -1),

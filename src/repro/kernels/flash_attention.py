@@ -162,23 +162,25 @@ def flash_attention_fwd(q, k, v, q_seg, k_seg, q_pos, k_pos, *, scale,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, kv_blocks=s // block_k)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(g, hg, t // block_q, s // block_k),
-        in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
-                  sp["qmeta"], sp["kmeta"]],
-        out_specs=[sp["qv"], sp["stat"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, hg, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, *_meta(q_seg, k_seg, q_pos, k_pos))
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(g, hg, t // block_q, s // block_k),
+            in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
+                      sp["qmeta"], sp["kmeta"]],
+            out_specs=[sp["qv"], sp["stat"]],
+            out_shape=[
+                jax.ShapeDtypeStruct((g, hg, t, dv), q.dtype),
+                jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+            name="flash_fwd",
+            interpret=interpret,
+        )(q, k, v, *_meta(q_seg, k_seg, q_pos, k_pos))
     return out, lse[..., 0]
 
 
@@ -234,25 +236,28 @@ def flash_attention_fwd_carry(q, k, v, q_seg, k_seg, q_pos, k_pos,
     kernel = functools.partial(
         _fwd_carry_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, kv_blocks=s // block_k)
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid=(g, hg, t // block_q, s // block_k),
-        in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
-                  sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"],
-                  sp["stat"]],
-        out_specs=[sp["qv"], sp["stat"], sp["stat"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, hg, t, dv), jnp.float32),
-            jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
-            jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, *_meta(q_seg, k_seg, q_pos, k_pos), acc, _col(m), _col(l))
+    with jax.named_scope("flash_fwd_carry"):
+        acc, m, l = pl.pallas_call(
+            kernel,
+            grid=(g, hg, t // block_q, s // block_k),
+            in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
+                      sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"],
+                      sp["stat"]],
+            out_specs=[sp["qv"], sp["stat"], sp["stat"]],
+            out_shape=[
+                jax.ShapeDtypeStruct((g, hg, t, dv), jnp.float32),
+                jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
+                jax.ShapeDtypeStruct((g, hg, t, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+            name="flash_fwd_carry",
+            interpret=interpret,
+        )(q, k, v, *_meta(q_seg, k_seg, q_pos, k_pos), acc, _col(m),
+          _col(l))
     return acc, m[..., 0], l[..., 0]
 
 
@@ -337,33 +342,40 @@ def flash_attention_bwd(q, k, v, q_seg, k_seg, q_pos, k_pos, out, lse, do, *,
                    softcap=softcap)
 
     sp = _specs(block_q, block_k, dk_dim, dv_dim)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, kv_blocks=s // block_k, **mask_kw),
-        grid=(g, hg, t // block_q, s // block_k),
-        in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
-                  sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"], sp["qv"]],
-        out_specs=sp["q"],
-        out_shape=jax.ShapeDtypeStruct((g, hg, t, dk_dim), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dk_dim), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, kv_blocks=s // block_k,
+                              **mask_kw),
+            grid=(g, hg, t // block_q, s // block_k),
+            in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
+                      sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"],
+                      sp["qv"]],
+            out_specs=sp["q"],
+            out_shape=jax.ShapeDtypeStruct((g, hg, t, dk_dim), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, dk_dim), jnp.float32)],
+            name="flash_dq",
+            interpret=interpret,
+        )(*args)
 
     sp = _specs(block_q, block_k, dk_dim, dv_dim, kv_major=True)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, q_blocks=t // block_q, hg=hg,
-                          **mask_kw),
-        grid=(g, s // block_k, hg, t // block_q),
-        in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
-                  sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"], sp["qv"]],
-        out_specs=[sp["k"], sp["v"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, s, dk_dim), k.dtype),
-            jax.ShapeDtypeStruct((g, s, dv_dim), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dk_dim), jnp.float32),
-            pltpu.VMEM((block_k, dv_dim), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, q_blocks=t // block_q, hg=hg,
+                              **mask_kw),
+            grid=(g, s // block_k, hg, t // block_q),
+            in_specs=[sp["q"], sp["k"], sp["v"], sp["qmeta"], sp["kmeta"],
+                      sp["qmeta"], sp["kmeta"], sp["qv"], sp["stat"],
+                      sp["qv"]],
+            out_specs=[sp["k"], sp["v"]],
+            out_shape=[
+                jax.ShapeDtypeStruct((g, s, dk_dim), k.dtype),
+                jax.ShapeDtypeStruct((g, s, dv_dim), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, dk_dim), jnp.float32),
+                pltpu.VMEM((block_k, dv_dim), jnp.float32),
+            ],
+            name="flash_dkv",
+            interpret=interpret,
+        )(*args)
     return dq, dk, dv

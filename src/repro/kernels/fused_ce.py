@@ -79,18 +79,21 @@ def fused_ce_fwd(logits, labels, *, interpret, block_t=256, block_v=2048):
     t, v = logits.shape
     block_t, block_v, grid = _grid(t, v, block_t, block_v)
     tok = pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
+    panel = pl.BlockSpec((block_t, block_v), lambda i, j: (i, j))
     col = jax.ShapeDtypeStruct((t, 1), jnp.float32)
     kernel = functools.partial(_fwd_kernel, v_blocks=grid[1], vocab=v,
                                block_v=block_v)
-    nll, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)), tok],
-        out_specs=[tok, tok],
-        out_shape=[col, col],
-        scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3,
-        interpret=interpret,
-    )(logits, labels[:, None])
+    with jax.named_scope("fused_ce_fwd"):
+        nll, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[panel, tok],
+            out_specs=[tok, tok],
+            out_shape=[col, col],
+            scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3,
+            name="fused_ce_fwd",
+            interpret=interpret,
+        )(logits, labels[:, None])
     return nll[:, 0], lse[:, 0]
 
 
@@ -101,11 +104,13 @@ def fused_ce_bwd(logits, labels, lse, g, *, interpret, block_t=256,
     block_t, block_v, grid = _grid(t, v, block_t, block_v)
     tok = pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
     panel = pl.BlockSpec((block_t, block_v), lambda i, j: (i, j))
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, vocab=v, block_v=block_v),
-        grid=grid,
-        in_specs=[panel, tok, tok, tok],
-        out_specs=panel,
-        out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
-        interpret=interpret,
-    )(logits, labels[:, None], lse[:, None], g[:, None])
+    with jax.named_scope("fused_ce_bwd"):
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, vocab=v, block_v=block_v),
+            grid=grid,
+            in_specs=[panel, tok, tok, tok],
+            out_specs=panel,
+            out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
+            name="fused_ce_bwd",
+            interpret=interpret,
+        )(logits, labels[:, None], lse[:, None], g[:, None])

@@ -100,13 +100,12 @@ def _analyze_trace_dir(trace_dir):
     """Merge every per-process trace in ``trace_dir`` onto the cluster
     timeline (workers export there on exit via $REPRO_TRACE_DIR; the
     controller's own trace is written just before this runs) and return
-    (attribution records, mfu/goodput dict) — or (None, None) when
-    there is nothing to merge."""
+    (attribution records, goodput dict) — or (None, None) when there is
+    nothing to merge."""
     import glob
     import json
 
-    from repro.obs.analyze import (attribute_steps, merge_traces,
-                                   mfu_goodput)
+    from repro.obs.analyze import attribute_steps, goodput, merge_traces
     paths = sorted(p for p in
                    glob.glob(os.path.join(trace_dir, "trace_*.json"))
                    if "merged" not in os.path.basename(p))
@@ -120,7 +119,7 @@ def _analyze_trace_dir(trace_dir):
     print(f"merged cluster trace ({len(paths)} processes) -> {out}",
           flush=True)
     attribution = attribute_steps(merged)
-    return attribution, mfu_goodput(merged, attribution)
+    return attribution, goodput(merged, attribution)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -187,7 +186,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "(workers via REPRO_TRACE_DIR) and the launcher "
                          "merges them onto one wall-clock timeline "
                          "(trace_merged.json) with time attribution and "
-                         "MFU/goodput in the --report; implies --trace")
+                         "goodput in the --report; implies --trace and "
+                         "the bytes ledger")
     ap.add_argument("--no-anomaly", action="store_true",
                     help="disable the controller's online anomaly "
                          "detector (straggler / wave-gap / throughput "
@@ -211,6 +211,10 @@ def main(argv=None):
     if args.trace_dir:
         os.makedirs(args.trace_dir, exist_ok=True)
         os.environ["REPRO_TRACE_DIR"] = args.trace_dir
+        # the wave spans carry the bytes ledger's stamps for
+        # `python -m repro.obs.analyze <dir>/trace_*.json --comm`
+        obs_configure(ledger=True)
+        os.environ["REPRO_LEDGER"] = "1"    # --ctrl workers inherit
     if args.metrics_out:
         obs_configure(metrics_path=args.metrics_out)
     get_recorder().install_excepthook()
@@ -224,17 +228,17 @@ def main(argv=None):
             if args.trace_out:
                 get_tracer().to_chrome(args.trace_out)
                 print(f"trace -> {args.trace_out}", flush=True)
-            attribution = mfu = None
+            attribution = good = None
             if args.trace_dir:
                 # Workers already exported on shutdown; add ours, merge.
                 get_tracer().to_chrome(os.path.join(
                     args.trace_dir, f"trace_controller_{os.getpid()}.json"))
-                attribution, mfu = _analyze_trace_dir(args.trace_dir)
+                attribution, good = _analyze_trace_dir(args.trace_dir)
             if args.report:
                 print(render_report(
                     metrics=get_metrics(),
                     calib=ctl.calib.summary() if ctl is not None else None,
-                    attribution=attribution, mfu=mfu,
+                    attribution=attribution, goodput=good,
                     advisories=ctl.advisories if ctl is not None else None,
                     telemetry=ctl.telemetry_summary()
                     if ctl is not None else None,

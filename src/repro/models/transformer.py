@@ -298,8 +298,9 @@ def block_forward(bp, cfg: ModelConfig, rt: Runtime, x, seg, pos,
     window = cfg.window if code == "l" else 0
     h = L.rmsnorm(bp["norm1"], x, cfg.norm_eps)
     if code in ("g", "l"):
-        h = _attention_block(bp["attn"], cfg, rt, h, seg, pos, window,
-                             collect=collect)
+        with jax.named_scope("attention"):
+            h = _attention_block(bp["attn"], cfg, rt, h, seg, pos, window,
+                                 collect=collect)
     elif code == "m":
         h = _ssm_block(bp["mamba"], cfg, rt, h, seg, code, "mamba")
     else:
@@ -314,7 +315,8 @@ def block_forward(bp, cfg: ModelConfig, rt: Runtime, x, seg, pos,
     elif "moe" in bp:
         h = _moe_block(bp["moe"], cfg, rt, h)
     else:
-        h = _ffn_block(bp["mlp"], cfg, h)
+        with jax.named_scope("mlp"):
+            h = _ffn_block(bp["mlp"], cfg, h)
     if cfg.post_block_norm:
         h = L.rmsnorm(bp["postnorm2"], h, cfg.norm_eps)
     return x + h.astype(x.dtype)

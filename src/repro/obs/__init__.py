@@ -28,8 +28,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.obs.analyze import (attribute_steps, comm_summary, merge_traces,
-                               mfu_goodput)
+from repro.obs.analyze import (attribute_steps, comm_summary, goodput,
+                               merge_traces)
 from repro.obs.anomaly import Advisory, AnomalyConfig, AnomalyDetector
 from repro.obs.ledger import Ledger, ledger_enabled, set_ledger_enabled
 from repro.obs.metrics import MetricsRegistry, get_metrics
@@ -48,7 +48,7 @@ __all__ = [
     "NONFINITE_SEVERITY", "plan_fingerprint", "nonfinite_signature",
     "get_metrics", "get_recorder", "get_tracer", "set_tracer",
     "monotime", "render_report", "validate_chrome_trace", "configure",
-    "merge_traces", "attribute_steps", "mfu_goodput", "comm_summary",
+    "merge_traces", "attribute_steps", "goodput", "comm_summary",
     "ledger_enabled", "set_ledger_enabled",
 ]
 

@@ -1,5 +1,5 @@
 """Cluster analytics: merge per-process traces, attribute step time,
-price waves with the Eq. 2 cost model (MFU / goodput).
+and report goodput.
 
 PR 7 built the collection side — spans, metrics, streamed telemetry —
 and every Chrome trace carries an ``otherData.wall_anchor`` pair
@@ -15,23 +15,17 @@ the consumer:
 
 * `attribute_steps` decomposes each (step × lane) window into
   **compute** (wave/round spans minus nested compiles), **dispatch**
-  (plan / materialize / apply / checkpoint), **bubble** (uncovered time
+  (the trainer's other host spans: plan / grads_init / materialize /
+  h2d / wave_post / apply / step_end / checkpoint), **bubble** (uncovered time
   between the first and last compute span — the wave-gap the planner's
   makespan model calls bubble) and **stall** (compile time + uncovered
   time outside the compute envelope).  The four buckets sum to the
   window by construction — the invariant the obs bench gates at 5%
   against the measured step wall.
 
-* `mfu_goodput` prices every dispatched wave with the planner's Eq. 2
-  FLOPs model: the trainer stamps each wave/round span with its modeled
-  per-rank cost (``cost_max`` / ``cost_sum`` seconds, embedding
-  peak_flops x assumed-MFU via `core.offload.analytic_coeffs`).  A
-  fleet scale (median measured-wall / cost_max over warm waves) removes
-  the model's absolute error; what remains is model-relative
-  utilization — useful fleet-seconds / (hdp x wall) — per step and
-  cumulative.  Goodput counts only the FINAL occurrence of each step
-  index (a step replayed after elastic recovery was wasted work) over
-  the whole trace extent, recoveries and re-plans included.
+* `goodput` divides the final-step wall — each step index once, a
+  step replayed after elastic recovery was wasted work — by the whole
+  trace extent, recoveries and re-plans included.
 
 CLI::
 
@@ -45,14 +39,13 @@ import json
 import sys
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from repro.obs.trace import validate_chrome_trace
 
 #: Span-name taxonomy (train/trainer.py, sched/service.py,
 #: ctrl/controller.py) -> attribution bucket.
 COMPUTE_SPANS = ("wave", "round")
-DISPATCH_SPANS = ("plan", "materialize", "apply", "checkpoint",
+DISPATCH_SPANS = ("plan", "grads_init", "materialize", "h2d", "wave_post",
+                  "apply", "step_end", "checkpoint",
                   "plan_window", "materialize_ahead", "plan_pool")
 STALL_SPANS = ("compile", "await_step")
 
@@ -191,8 +184,8 @@ def attribute_steps(doc: dict) -> List[dict]:
     ``compute_s + dispatch_s + bubble_s + stall_s == window_s`` (exact
     up to float rounding — ``check`` reports the ratio).  A lane whose
     step window is a single enclosing span (the controller's
-    ``ctrl_step``) is peeled: the wrapper defines the window and its
-    children are attributed."""
+    ``ctrl_step``, the trainer's ``train_step``) is peeled: the wrapper
+    defines the window and its children are attributed."""
     names = _proc_names(doc)
     recs: List[dict] = []
     for (pid, tid), evs in sorted(_step_spans(doc).items()):
@@ -266,91 +259,32 @@ def attribute_steps(doc: dict) -> List[dict]:
 
 
 # ---------------------------------------------------------------------------
-# MFU / goodput
+# goodput
 # ---------------------------------------------------------------------------
 
-def mfu_goodput(doc: dict,
-                attribution: Optional[List[dict]] = None) -> dict:
-    """Price every dispatched wave with the Eq. 2 cost model against its
-    measured wall.  Wave/round spans carry ``cost_max`` / ``cost_sum``
-    (modeled per-rank seconds from `Wave.costs`) and ``tokens``; the
-    fleet scale — median(measured wall / cost_max) over warm waves —
-    removes the model's absolute calibration so ``mfu`` is
-    model-relative utilization: useful fleet-seconds / (hdp × wall).
-    Only each (lane, step, idx)'s FINAL occurrence counts (replays
-    after elastic recovery were waste); ``goodput`` divides final-step
-    wall by the full trace extent, recoveries included."""
+def goodput(doc: dict, attribution: Optional[List[dict]] = None) -> dict:
+    """Final-step wall over the trace extent.  Each step index counts
+    once, at its widest (step × lane) window from `attribute_steps` —
+    a step replayed after elastic recovery was wasted work — against
+    the extent of every span in the trace, recoveries and re-plans
+    included."""
     if attribution is None:
         attribution = attribute_steps(doc)
-    waves: Dict[Tuple[int, int, int], dict] = {}
-    extent_lo, extent_hi = np.inf, -np.inf
-    for e in doc.get("traceEvents", []):
-        if e.get("ph") != "X":
-            continue
-        t0, t1 = float(e["ts"]), float(e["ts"]) + float(e["dur"])
-        extent_lo, extent_hi = min(extent_lo, t0), max(extent_hi, t1)
-        a = e.get("args") or {}
-        if e["name"] in COMPUTE_SPANS and "cost_max" in a:
-            key = (e["pid"], int(a.get("step", -1)), int(a.get("idx", 0)))
-            prev = waves.get(key)
-            if prev is None or t0 > prev["ts"]:    # final occurrence
-                waves[key] = {
-                    "ts": t0, "wall_s": (t1 - t0) / 1e6,
-                    "cost_max": float(a["cost_max"]),
-                    "cost_sum": float(a["cost_sum"]),
-                    "tokens": int(a.get("tokens", 0)),
-                    "hdp": len(a.get("composition") or []) or 1,
-                    "fresh": bool(a.get("fresh", False))}
-    if not waves:
-        return {"n_waves": 0, "mfu": None, "goodput": None}
-    # waves are SPMD — every worker lane times the same dispatch; keep
-    # one lane per (step, idx): the slowest (the fleet-visible wall)
-    fleet: Dict[Tuple[int, int], dict] = {}
-    for (pid, step, idx), w in waves.items():
-        k = (step, idx)
-        if k not in fleet or w["wall_s"] > fleet[k]["wall_s"]:
-            fleet[k] = w
-    warm = [w for w in fleet.values()
-            if not w["fresh"] and w["cost_max"] > 0]
-    pool = warm or [w for w in fleet.values() if w["cost_max"] > 0]
-    scale = float(np.median([w["wall_s"] / w["cost_max"] for w in pool])) \
-        if pool else 1.0
-
-    # final occurrence of each step: the widest step window across lanes
     step_windows: Dict[int, float] = {}
     for r in attribution:
         cur = step_windows.get(r["step"], 0.0)
         step_windows[r["step"]] = max(cur, r["window_s"])
-    per_step: List[dict] = []
-    useful_fleet_s = 0.0
-    denom_fleet_s = 0.0
-    for step in sorted(step_windows):
-        sw = [w for (s, _), w in fleet.items() if s == step]
-        if not sw:
-            continue
-        hdp = max(w["hdp"] for w in sw)
-        useful = sum(w["cost_sum"] * scale for w in sw)
-        wall = step_windows[step]
-        useful_fleet_s += useful
-        denom_fleet_s += hdp * wall
-        per_step.append({
-            "step": step, "wall_s": round(wall, 6),
-            "waves": len(sw),
-            "tokens": int(sum(w["tokens"] for w in sw)),
-            "mfu": round(useful / (hdp * wall), 4) if wall > 0 else None})
-    extent_s = max((extent_hi - extent_lo) / 1e6, 1e-9)
-    useful_wall = sum(step_windows.values())
-    tokens = sum(r["tokens"] for r in per_step)
-    return {"n_waves": len(fleet),
-            "scale": round(scale, 6),
-            "mfu": round(useful_fleet_s / denom_fleet_s, 4)
-            if denom_fleet_s > 0 else None,
-            "goodput": round(min(useful_wall / extent_s, 1.0), 4),
-            "useful_s": round(useful_wall, 6),
-            "total_s": round(extent_s, 6),
-            "tokens": int(tokens),
-            "tokens_per_s": round(tokens / extent_s, 1),
-            "per_step": per_step}
+    if not step_windows:
+        return {"n_steps": 0, "goodput": None}
+    xs = [(float(e["ts"]), float(e["ts"]) + float(e["dur"]))
+          for e in doc.get("traceEvents", []) if e.get("ph") == "X"]
+    extent_s = max((max(t1 for _, t1 in xs) - min(t0 for t0, _ in xs))
+                   / 1e6, 1e-9)
+    useful = sum(step_windows.values())
+    return {"n_steps": len(step_windows),
+            "goodput": round(min(useful / extent_s, 1.0), 4),
+            "useful_s": round(useful, 6),
+            "total_s": round(extent_s, 6)}
 
 
 # ---------------------------------------------------------------------------
@@ -418,7 +352,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs.analyze",
         description="Merge per-process Chrome traces into one cluster "
-                    "timeline, attribute step time, report MFU/goodput.")
+                    "timeline, attribute step time, report goodput.")
     ap.add_argument("traces", nargs="+", help="trace_*.json files")
     ap.add_argument("--metrics", default=None,
                     help="per-step metrics JSONL (launcher --metrics-out)"
@@ -436,7 +370,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     merged = merge_traces(args.traces)
     ok, problems = validate_chrome_trace(merged)
     attribution = attribute_steps(merged)
-    mfu = mfu_goodput(merged, attribution)
+    good = goodput(merged, attribution)
     comm = comm_summary(merged) if args.comm else None
     if args.out:
         with open(args.out, "w") as f:
@@ -446,14 +380,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.json:
         out = {"valid": ok, "problems": problems[:8],
                "n_events": len(merged["traceEvents"]),
-               "attribution": attribution, "mfu": mfu}
+               "attribution": attribution, "goodput": good}
         if comm is not None:
             out["comm"] = comm
         print(json.dumps(out, indent=1, sort_keys=True))
     else:
         from repro.obs.report import render_report
         print(render_report(metrics=metrics, attribution=attribution,
-                            mfu=mfu, comm=comm, title="cluster analysis "
+                            goodput=good, comm=comm,
+                            title="cluster analysis "
                             f"({len(args.traces)} trace(s), "
                             f"valid={ok})"))
         if not ok:

@@ -4,7 +4,7 @@ ByteScale's claims are claims about *bytes* — the communication optimizer
 "eliminates redundant communication for short sequences" and "compresses
 communication for long sequences by selective offloading" — so this module
 closes the loop the time-based observability stack (spans, attribution,
-MFU) leaves open: for every dispatched wave it produces a **predicted**
+goodput) leaves open: for every dispatched wave it produces a **predicted**
 byte count derived purely from the plan + model config, and a **measured**
 byte count tallied from the instrumented hot paths, per collective kind:
 
@@ -65,9 +65,9 @@ _enabled = os.environ.get("REPRO_LEDGER", "") not in ("", "0", "false")
 
 
 def ledger_enabled() -> bool:
-    """Standalone enable knob (``REPRO_LEDGER=1`` or `obs.configure
-    (ledger=True)`).  The trainer also activates the ledger whenever
-    tracing is on, so traced runs are always byte-stamped."""
+    """The ledger's one enable knob (``REPRO_LEDGER=1`` or
+    `obs.configure(ledger=True)`), independent of tracing: a traced run
+    does the same work as an untraced one."""
     return _enabled
 
 

@@ -14,9 +14,9 @@ production loop watches:
 * compile-cache hit rate (the NCCL-group-cache analogue);
 * serving TTFT p50/p99, end-to-end latency and queue depth;
 * the analysis layer (obs/analyze.py, obs/anomaly.py): the per-step
-  time-attribution table (compute/dispatch/bubble/stall), MFU/goodput
-  against the Eq. 2 cost model, the controller's advisory log and the
-  per-worker telemetry-stream summary.
+  time-attribution table (compute/dispatch/bubble/stall), goodput, the
+  controller's advisory log and the per-worker telemetry-stream
+  summary.
 
 Histogram-backed lines (dispatch wall p50/p99) read the bucketed
 `Histogram.quantile` values straight off the metrics snapshot — no
@@ -60,7 +60,7 @@ def render_report(history: Optional[List[Dict]] = None,
                   calib: Optional[Dict] = None,
                   serve_records: Optional[Sequence[Dict]] = None,
                   attribution: Optional[List[Dict]] = None,
-                  mfu: Optional[Dict] = None,
+                  goodput: Optional[Dict] = None,
                   advisories: Optional[Sequence[Dict]] = None,
                   telemetry: Optional[Dict] = None,
                   comm: Optional[Dict] = None,
@@ -69,8 +69,8 @@ def render_report(history: Optional[List[Dict]] = None,
     dict (a live registry is accepted too); ``calib`` is
     `OnlineCalibrator.summary()`; ``serve_records`` is a list of request
     telemetry dicts (`Request.telemetry()` / controller request_log);
-    ``attribution`` / ``mfu`` come from `obs.analyze.attribute_steps` /
-    `obs.analyze.mfu_goodput`; ``advisories`` is the controller's
+    ``attribution`` / ``goodput`` come from `obs.analyze.attribute_steps`
+    / `obs.analyze.goodput`; ``advisories`` is the controller's
     advisory log and ``telemetry`` its `telemetry_summary()`; ``comm``
     is the bytes-ledger audit (`obs.analyze.comm_summary`, or a ledger/
     controller `summary()`/`ledger_summary()` dict)."""
@@ -124,21 +124,13 @@ def render_report(history: Optional[List[Dict]] = None,
         if len(attribution) > 24:
             out.append(_line("...", f"({len(attribution) - 24} more)"))
 
-    if mfu and mfu.get("n_waves"):
-        out.append("-- MFU / goodput (Eq. 2 priced vs measured) --")
-        if mfu.get("mfu") is not None:
-            out.append(_line("MFU (model-relative, cumulative)",
-                             f"{mfu['mfu'] * 100:7.2f}%"))
-        if mfu.get("goodput") is not None:
-            out.append(_line("goodput (useful / total wall)",
-                             f"{mfu['goodput'] * 100:7.2f}%"))
+    if goodput and goodput.get("goodput") is not None:
+        out.append("-- goodput --")
+        out.append(_line("goodput (final-step / total wall)",
+                         f"{goodput['goodput'] * 100:7.2f}%"))
         out.append(_line("useful / total",
-                         f"{_fmt_s(mfu['useful_s'])} /"
-                         f"{_fmt_s(mfu['total_s'])}"))
-        if mfu.get("tokens_per_s"):
-            out.append(_line("tokens / s", f"{mfu['tokens_per_s']:10.1f}"))
-        out.append(_line("waves priced / fleet scale",
-                         f"{mfu['n_waves']} / {mfu.get('scale', 0):.4f}"))
+                         f"{_fmt_s(goodput['useful_s'])} /"
+                         f"{_fmt_s(goodput['total_s'])}"))
 
     if comm and (comm.get("n_dispatch") or comm.get("n")):
         out.append("-- comm / memory (bytes ledger: predicted vs "

@@ -12,6 +12,13 @@ recorded per tracer (``otherData.wall_anchor``) so traces from multiple
 processes can be aligned on their wall clocks without per-event wall
 reads.
 
+One clock with the device: while enabled, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name (and a `step_span` a
+``StepTraceAnnotation``), so under a ``jax.profiler`` session the spans
+land on the profiler's host plane beside the device's ops and open in
+XProf or Perfetto with them.  ``jax.profiler`` is imported on the first
+enabled span, so the module works without JAX.
+
 Disabled-by-default zero-overhead contract: when tracing is off,
 ``span()`` returns one shared no-op singleton — no span object, no event
 record, no lock acquisition is ever allocated or taken on the hot path.
@@ -49,18 +56,39 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_profiler = None        # jax.profiler once a span needs it; False: no JAX
+
+
+def _annotation(name: str, step_group: Optional[str], step: int):
+    """The profiler annotation a span enters: a ``TraceAnnotation`` of
+    its name, or for a step span a ``StepTraceAnnotation`` of its group
+    (the profiler's ``Steps`` line); None without JAX."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler as prof
+        except ImportError:
+            prof = False
+        _profiler = prof
+    if not _profiler:
+        return None
+    if step_group is None:
+        return _profiler.TraceAnnotation(name)
+    return _profiler.StepTraceAnnotation(step_group, step_num=step)
+
 
 class _Span:
-    __slots__ = ("_tracer", "name", "pid", "tid", "t0", "args")
+    __slots__ = ("_tracer", "name", "pid", "tid", "t0", "args", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, pid: int,
-                 args: Optional[dict]):
+                 args: Optional[dict], ann):
         self._tracer = tracer
         self.name = name
         self.pid = pid
         self.tid = threading.get_ident()
         self.args = args
         self.t0 = 0.0
+        self._ann = ann
 
     def set(self, key: str, value) -> None:
         if self.args is None:
@@ -68,11 +96,15 @@ class _Span:
         self.args[key] = value
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = monotime()
         return self
 
     def __exit__(self, *exc):
         self._tracer._complete(self)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -108,7 +140,16 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, self.pid if pid is None else int(pid),
-                     args or None)
+                     args or None, _annotation(name, None, 0))
+
+    def step_span(self, name: str, step: int, group: str, **args):
+        """A `span` stamped ``step=step`` that also marks step ``step``
+        of ``group`` on the profiler's ``Steps`` line, for XProf's step
+        breakdown.  The shared no-op singleton when disabled."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self, name, self.pid, {"step": step, **args},
+                     _annotation(name, group, step))
 
     def instant(self, name: str, pid: Optional[int] = None,
                 ts: Optional[float] = None, **args) -> None:

@@ -29,8 +29,9 @@ from repro.parallel.zero1 import opt_state_pspecs
 
 def loss_fn(params, cfg: ModelConfig, rt: Runtime, batch):
     hidden = forward_hidden(params, cfg, rt, batch)
-    return token_ce_loss(params, cfg, rt, hidden, batch["labels"],
-                         batch["seg"], batch["denom"])
+    with jax.named_scope("loss"):
+        return token_ce_loss(params, cfg, rt, hidden, batch["labels"],
+                             batch["seg"], batch["denom"])
 
 
 def make_train_step(cfg: ModelConfig, rt: Runtime, opt_cfg: adamw.AdamWConfig):
@@ -90,6 +91,18 @@ def make_accum_steps(cfg: ModelConfig, rt: Runtime,
         return new_params, new_opt, om
 
     return grad_step, apply_step
+
+
+def named_program(kind: str, composition, step_fn, rt_wave: Runtime):
+    """``step_fn(params, grads, batch, rt_wave)`` as a function of
+    (params, grads, batch) named ``<kind>_<composition>`` (``wave_1``,
+    ``round_2_1_1``): jitted, its XLA module is ``jit_wave_1``, the name
+    the compiled HLO and the profiler's trace carry."""
+    def program(params, grad_accum, batch):
+        return step_fn(params, grad_accum, batch, rt_wave)
+    program.__name__ = program.__qualname__ = "_".join(
+        [kind] + [str(int(c)) for c in composition])
+    return program
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ per-rank wave-time EMAs feed the scheduler's straggler weights.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from dataclasses import dataclass, field
@@ -47,7 +48,7 @@ from repro.parallel.pipeline import (assert_pipeline_ready,
                                      rounds_splitter)
 from repro.parallel.sharding import (Runtime, params_pspecs,
                                      shardings_from_pspecs)
-from repro.train.train_step import make_accum_steps
+from repro.train.train_step import make_accum_steps, named_program
 
 
 @dataclass
@@ -139,8 +140,8 @@ class Trainer:
             quadratic=scheduler.spec.quadratic, ema=tcfg.straggler_ema)
         self.ledger = None           # lazy bytes ledger (obs/ledger.py):
                                      # built on the first dispatch with
-                                     # tracing or REPRO_LEDGER on, priced
-                                     # from the live plan geometry
+                                     # the ledger on, priced from the
+                                     # live plan geometry
         self.last_ledger_record = None  # ctrl-worker hook: the most
                                         # recent per-dispatch ledger
                                         # record, streamed on heartbeats
@@ -249,15 +250,17 @@ class Trainer:
 
     def _wave_fn(self, composition, c_mult, offload_ratio):
         """-> (jitted executable, fresh) — ``fresh`` marks a cache miss
-        (the dispatch will pay a compile; the calibrator skips it)."""
+        (the dispatch will pay a compile; the calibrator skips it).  The
+        executable is named for its composition (`named_program`:
+        ``jit_wave_2_1_1`` on the device)."""
         key = (composition, c_mult, round(offload_ratio, 2))
         fresh = key not in self._exec_cache
         get_metrics().counter("trainer.compile_miss" if fresh
                               else "trainer.compile_hit").inc()
         if fresh:
-            rt_wave = self._wave_rt(composition, offload_ratio)
             self._exec_cache[key] = jax.jit(
-                lambda p, g, b: self.grad_step(p, g, b, rt_wave),
+                named_program("wave", composition, self.grad_step,
+                              self._wave_rt(composition, offload_ratio)),
                 donate_argnums=(1,))
         return self._exec_cache[key], fresh
 
@@ -270,9 +273,9 @@ class Trainer:
         get_metrics().counter("trainer.compile_miss" if fresh
                               else "trainer.compile_hit").inc()
         if fresh:
-            rt_round = self._wave_rt(composition, offload_ratio)
             self._exec_cache[key] = jax.jit(
-                lambda p, g, b: self.pipeline_grad_step(p, g, b, rt_round),
+                named_program("round", composition, self.pipeline_grad_step,
+                              self._wave_rt(composition, offload_ratio)),
                 donate_argnums=(1,))
         return self._exec_cache[key], fresh
 
@@ -364,13 +367,14 @@ class Trainer:
         else:
             self.calib.observe(costs, seconds=float(measured), **kw)
 
-    def _ensure_ledger(self, tr):
+    def _ensure_ledger(self):
         """Bytes ledger (obs/ledger.py), built lazily on the first
-        dispatch with tracing or REPRO_LEDGER on — and rebuilt after an
-        elastic resize (the HDP world size prices ZeRO-1 collectives and
-        the optimizer-shard term of the HBM watermark).  Returns None
-        when the ledger is off (zero cost on the disabled path)."""
-        if not (tr.enabled or ledger_mod.ledger_enabled()):
+        dispatch with the ledger on (REPRO_LEDGER or `obs.configure
+        (ledger=True)`) — and rebuilt after an elastic resize (the HDP
+        world size prices ZeRO-1 collectives and the optimizer-shard
+        term of the HBM watermark).  Returns None when the ledger is off
+        (zero cost on the disabled path)."""
+        if not ledger_mod.ledger_enabled():
             return None
         if self.ledger is None or self.ledger.hdp != self.sched.hdp:
             self.ledger = ledger_mod.Ledger(
@@ -382,16 +386,13 @@ class Trainer:
             self.ledger.set_step_bytes(zero1_bytes(self.params, self.rt))
         return self.ledger
 
-    def _dispatch(self, tr, fn, grads, batch, name: str, idx: int,
-                  composition, fresh: bool, waves=None, c_mult: int = 1,
+    def _dispatch(self, tr, step: int, fn, grads, batch, name: str,
+                  idx: int, composition, fresh: bool, c_mult: int = 1,
                   offload_ratio: float = 0.0, n_waves: int = 1):
-        """Run one jitted executable under a span; a fresh cache entry
-        pays its compile inside the nested "compile" span.  When tracing
-        is on, the span is stamped with the dispatch's Eq. 2 price —
-        modeled per-rank cost max/sum (`Wave.costs`, seconds) and token
-        count — so exported traces are self-contained inputs for
-        `obs.analyze.mfu_goodput`; disabled tracing skips the pricing
-        entirely (zero-overhead contract).
+        """Run one jitted executable under a span: ``wave_dispatch`` the
+        call up to its return, ``wave_wait`` the blocking fetch of its
+        loss; a fresh cache entry pays its compile inside both, under
+        the nested "compile" span.
 
         Bytes ledger: a fresh compile's trace runs under
         ``ledger.capture()``, harvesting the instrumented comm sites'
@@ -400,32 +401,24 @@ class Trainer:
         predicted/measured record (plus an allocator HBM peak sample
         where the backend exposes one) on the ledger, the span, and
         ``last_ledger_record`` for the ctrl worker's heartbeat."""
-        led = self._ensure_ledger(tr)
-        extra = {}
-        if tr.enabled and waves:
-            costs = np.sum([np.asarray(w.costs) for w in waves], axis=0)
-            extra = {"cost_max": round(float(costs.max(initial=0.0)), 9),
-                     "cost_sum": round(float(costs.sum()), 9),
-                     "tokens": int(sum(p.length for w in waves
-                                       for slot in w.slots
-                                       for p in slot))}
-        with tr.span(name, step=self.step, idx=idx,
-                     composition=composition, fresh=fresh, **extra) as sp:
+        led = self._ensure_ledger()
+        capture = fresh and led is not None
+        with tr.span(name, step=step, idx=idx, composition=composition,
+                     fresh=fresh) as sp:
             t_w = self._clock()
-            if fresh:
-                with tr.span("compile", step=self.step,
-                             composition=composition):
-                    if led is not None:
-                        with ledger_mod.capture() as tally:
-                            grads, metrics = fn(self.params, grads, batch)
-                        self._ledger_meas[id(fn)] = dict(tally)
-                    else:
-                        grads, metrics = fn(self.params, grads, batch)
-                    loss = float(metrics["loss"])    # blocks: compiled
-            else:                                    # AND executed
-                grads, metrics = fn(self.params, grads, batch)
-                loss = float(metrics["loss"])        # blocks: completed
+            with (tr.span("compile", step=step, idx=idx,
+                          composition=composition)
+                  if fresh else contextlib.nullcontext()):
+                with tr.span("wave_dispatch", step=step, idx=idx), \
+                        (ledger_mod.capture() if capture
+                         else contextlib.nullcontext()) as tally:
+                    grads, metrics = fn(self.params, grads, batch)
+                with tr.span("wave_wait", step=step, idx=idx):
+                    # blocks until the program has run (and compiled)
+                    loss = float(metrics["loss"])
             dt = self._clock() - t_w
+            if capture:
+                self._ledger_meas[id(fn)] = dict(tally)
             if led is not None:
                 hbm = compat.device_memory_stats().get("peak_bytes_in_use")
                 rec = led.record_dispatch(
@@ -478,25 +471,37 @@ class Trainer:
                 self._numerics_dump_reason = f"numerics_{f['reason']}"
 
     def train_step(self) -> Dict:
+        """One global batch: plan; per wave (or pipelined round)
+        materialize its buffers, copy them in, dispatch and observe;
+        then one optimizer apply.  With tracing on, every host stretch
+        of the step is a span (README, "Observability") inside one
+        ``train_step`` span, which also marks the step on the
+        profiler's ``Steps`` line."""
         tr = get_tracer()
+        with tr.step_span("train_step", self.step, "train"):
+            return self._train_step(tr, self.step)
+
+    def _train_step(self, tr, step: int) -> Dict:
         mx = get_metrics()
         t0 = self._clock()
         n_find0 = len(self.numerics.findings)
-        with tr.span("plan", step=self.step):
+        with tr.span("plan", step=step):
             if self.tcfg.sched_async and hasattr(self.sched, "get_step"):
                 plan, pre_waves = self.sched.get_step(self.step)
             else:
                 plan, pre_waves = self.sched.plan_step(self.step), None
         denom = float(plan.denom)
-        grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                             self.params)
+        with tr.span("grads_init", step=step):
+            grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                                 self.params)
         losses = []
-        rec_extra = {}
+        n_rounds = None
         if self.pipelined:
             # pipelined executor: the wave queue runs as rounds of like
             # waves, each round one wavefront schedule (parallel/pipeline);
             # round r+1 materializes in the background while r executes
             rounds = pipeline_rounds(plan, self.tcfg.max_round_waves)
+            n_rounds = len(rounds)
             # pre_waves: stacked [M, ...] round buffers the scheduler
             # service pre-built (materialize-ahead; its rounds_fn mirrors
             # this split).  Fallback is the prefetch iterator, driven
@@ -507,77 +512,100 @@ class Trainer:
             for i in range(len(rounds)):
                 # the materialize span measures the wait for the round's
                 # buffers (near-zero when materialize-ahead got there)
-                with tr.span("materialize", step=self.step, idx=i):
+                with tr.span("materialize", step=step, idx=i):
                     stacked = next(round_iter)
                 rd = rounds[i]
-                batch = {k: jnp.asarray(v) for k, v in stacked.items()}
-                batch["denom"] = jnp.float32(
-                    float("nan") if self._nan_fault_hits(i) else denom)
+                with tr.span("h2d", step=step, idx=i):
+                    batch = self._to_device(stacked, i, denom)
                 fn, fresh = self._round_fn(rd.composition, rd.c_mult,
                                            rd.offload_ratio,
                                            len(rd.wave_ids))
                 rd_waves = [plan.waves[i] for i in rd.wave_ids]
                 grads, loss, dt = self._dispatch(
-                    tr, fn, grads, batch, "round", i, rd.composition,
-                    fresh, waves=rd_waves, c_mult=rd.c_mult,
-                    offload_ratio=rd.offload_ratio,
+                    tr, step, fn, grads, batch, "round", i, rd.composition,
+                    fresh, c_mult=rd.c_mult, offload_ratio=rd.offload_ratio,
                     n_waves=len(rd.wave_ids))
-                losses.append(loss)
-                self.last_wave_findings = \
-                    self.numerics.observe_wave(self.step, i, loss)
-                self._note_findings(self.last_wave_findings, mx)
-                mx.histogram("trainer.dispatch_s").observe(dt)
-                wall = dt
-                if self.wave_time_fn is not None:
-                    dt = self.wave_time_fn(rd_waves)
-                self._observe(rd_waves, dt, fresh,
-                              modeled=self.wave_time_fn is not None,
-                              wall_s=wall)
+                with tr.span("wave_post", step=step, idx=i):
+                    losses.append(loss)
+                    self._wave_post(mx, i, loss, dt, fresh, rd_waves)
             for _ in round_iter:        # drain the prefetch epilogue so
                 pass                    # producer errors still surface
-            sched_stats = pipeline_schedule_stats(
-                plan, self.rt.num_stages, self.tcfg.max_round_waves)
-            rec_extra = {"rounds": len(rounds),
-                         "bubble_frac_pipeline":
-                             sched_stats["bubble_frac_pipeline"]}
         else:
             wave_iter = iter(pre_waves) if pre_waves is not None \
                 else self.loader.iter_step(self.step, plan)
             for i in range(len(plan.waves)):
-                with tr.span("materialize", step=self.step, idx=i):
+                with tr.span("materialize", step=step, idx=i):
                     lw = next(wave_iter)
                 wave = plan.waves[i]
-                batch = {k: jnp.asarray(v) for k, v in lw.batch.items()}
-                batch["denom"] = jnp.float32(
-                    float("nan") if self._nan_fault_hits(i) else denom)
+                with tr.span("h2d", step=step, idx=i):
+                    batch = self._to_device(lw.batch, i, denom)
                 fn, fresh = self._wave_fn(lw.composition, lw.c_mult,
                                           lw.offload_ratio)
                 grads, loss, dt = self._dispatch(
-                    tr, fn, grads, batch, "wave", i, lw.composition,
-                    fresh, waves=[wave], c_mult=lw.c_mult,
-                    offload_ratio=lw.offload_ratio)
-                losses.append(loss)
-                self.last_wave_findings = \
-                    self.numerics.observe_wave(self.step, i, loss)
-                self._note_findings(self.last_wave_findings, mx)
-                mx.histogram("trainer.dispatch_s").observe(dt)
-                wall = dt
-                if self.wave_time_fn is not None:
-                    dt = self.wave_time_fn(wave)
-                self._observe([wave], dt, fresh,
-                              modeled=self.wave_time_fn is not None,
-                              wall_s=wall)
+                    tr, step, fn, grads, batch, "wave", i, lw.composition,
+                    fresh, c_mult=lw.c_mult, offload_ratio=lw.offload_ratio)
+                with tr.span("wave_post", step=step, idx=i):
+                    losses.append(loss)
+                    self._wave_post(mx, i, loss, dt, fresh, [wave])
             for _ in wave_iter:         # drain the prefetch epilogue so
                 pass                    # producer errors still surface
-        with tr.span("apply", step=self.step):
-            self.params, self.opt_state, om = self._apply_jit(
-                self.params, self.opt_state, grads)
+        with tr.span("apply", step=step):
+            with tr.span("apply_dispatch", step=step):
+                self.params, self.opt_state, om = self._apply_jit(
+                    self.params, self.opt_state, grads)
             # ONE host fetch for the whole fused sentinel summary
             # (grad_norm + per-group norms + non-finite count + applied
             # flag) — the step pays the same single sync it used to pay
-            # for grad_norm alone
-            om = {k: np.asarray(v).item()
-                  for k, v in jax.device_get(om).items()}  # blocks: applied
+            # for grad_norm alone; it blocks until the apply has run
+            with tr.span("apply_fetch", step=step):
+                om = {k: np.asarray(v).item()
+                      for k, v in jax.device_get(om).items()}
+        with tr.span("step_end", step=step):
+            rec = self._end_step(plan, losses, om, t0, n_find0, n_rounds,
+                                 mx)
+        if self.ckpt and self.tcfg.ckpt_save \
+                and self.step % self.tcfg.ckpt_every == 0:
+            with tr.span("checkpoint", step=step):
+                self.ckpt.save(self.step, self.params, self.opt_state,
+                               self.data_state())
+                self._last_ckpt_step = self.step
+        return rec
+
+    def _to_device(self, host_batch, idx: int, denom: float) -> Dict:
+        """A wave's (or round's) host buffers and the step's loss
+        denominator as device arrays (the ``h2d`` span)."""
+        batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+        batch["denom"] = jnp.float32(
+            float("nan") if self._nan_fault_hits(idx) else denom)
+        return batch
+
+    def _wave_post(self, mx, idx: int, loss: float, dt: float,
+                   fresh: bool, waves) -> None:
+        """The host's work after one dispatch (the ``wave_post`` span):
+        the numerics monitor, the dispatch histogram and `_observe`.
+        The `wave_time_fn` hook prices a round's waves, or one wave."""
+        self.last_wave_findings = \
+            self.numerics.observe_wave(self.step, idx, loss)
+        self._note_findings(self.last_wave_findings, mx)
+        mx.histogram("trainer.dispatch_s").observe(dt)
+        wall = dt
+        if self.wave_time_fn is not None:
+            dt = self.wave_time_fn(waves if self.pipelined else waves[0])
+        self._observe(waves, dt, fresh,
+                      modeled=self.wave_time_fn is not None, wall_s=wall)
+
+    def _end_step(self, plan, losses, om, t0: float, n_find0: int,
+                  n_rounds: Optional[int], mx) -> Dict:
+        """Close the step (the ``step_end`` span): straggler feedback,
+        compiled keys to the scheduler, the step record, the numerics
+        monitor and provenance, and the per-step metrics export."""
+        rec_extra = {}
+        if n_rounds is not None:
+            sched_stats = pipeline_schedule_stats(
+                plan, self.rt.num_stages, self.tcfg.max_round_waves)
+            rec_extra = {"rounds": n_rounds,
+                         "bubble_frac_pipeline":
+                             sched_stats["bubble_frac_pipeline"]}
         # straggler feedback: *measured* per-rank speeds (the old loop
         # EMA'd the plan's own modeled costs — on a balanced plan every
         # rank looked identical and a real straggler was invisible)
@@ -647,12 +675,6 @@ class Trainer:
             get_recorder().dump(self._numerics_dump_reason)
             self._numerics_dump_reason = None
         mx.export_step(self.step)
-        if self.ckpt and self.tcfg.ckpt_save \
-                and self.step % self.tcfg.ckpt_every == 0:
-            with tr.span("checkpoint", step=self.step):
-                self.ckpt.save(self.step, self.params, self.opt_state,
-                               self.data_state())
-                self._last_ckpt_step = self.step
         return rec
 
     def run(self, steps: Optional[int] = None):

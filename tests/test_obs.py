@@ -383,7 +383,7 @@ def test_report_renders_sections():
     assert render_report() == "== observability report ==\n  (no data)"
 
 
-# -- cluster analytics: trace merge / attribution / MFU ------------------
+# -- cluster analytics: trace merge / attribution / goodput --------------
 def _span(tr, name, t0, t1, **args):
     tr.complete(name, t0, t1, tid=1, **args)
 
@@ -455,26 +455,27 @@ def test_attribution_sums_to_window():
         assert abs(r["check"] - 1.0) < 1e-6, r
 
 
-def test_mfu_goodput_prices_waves():
-    from repro.obs.analyze import mfu_goodput
+def test_goodput_counts_final_steps_over_extent():
+    """Final-step wall over the trace extent: a replayed step counts at
+    its widest window once; the stretch no step covers is lost."""
+    from repro.obs.analyze import goodput
     tr = Tracer(enabled=True, process="worker", pid=3)
     b = monotime()
-    kw = dict(cost_max=0.5, cost_sum=1.6, tokens=100,
-              composition=[1, 1, 1, 1], fresh=False)
-    _span(tr, "wave", b + 0.0, b + 1.0, step=0, idx=0, **kw)
-    _span(tr, "wave", b + 1.2, b + 2.2, step=0, idx=1, **kw)
-    out = mfu_goodput(tr.to_chrome())
-    assert out["n_waves"] == 2
-    assert out["scale"] == pytest.approx(2.0, rel=1e-3)  # wall/cost_max
-    # useful = 2 x 1.6 x 2.0 = 6.4 fleet-s over hdp(4) x window(2.2)
-    assert out["mfu"] == pytest.approx(6.4 / (4 * 2.2), abs=2e-3)
-    assert out["goodput"] == pytest.approx(1.0, abs=1e-6)
-    assert out["tokens"] == 200
-    assert out["per_step"][0]["waves"] == 2
-    # empty trace degrades explicitly
+    _span(tr, "train_step", b + 0.0, b + 1.0, step=0)
+    _span(tr, "wave", b + 0.1, b + 0.9, step=0, idx=0)
+    _span(tr, "recover", b + 1.0, b + 1.5)             # no step: lost
+    _span(tr, "train_step", b + 1.5, b + 2.0, step=1)
+    _span(tr, "wave", b + 1.6, b + 1.9, step=1, idx=0)
+    out = goodput(tr.to_chrome())
+    assert out["n_steps"] == 2
+    assert out["useful_s"] == pytest.approx(1.5, rel=1e-3)
+    assert out["total_s"] == pytest.approx(2.0, rel=1e-3)
+    assert out["goodput"] == pytest.approx(0.75, abs=1e-3)
+    assert "mfu" not in out
+    # a trace with no step-stamped span degrades explicitly
     empty = Tracer(enabled=True, process="x", pid=1)
-    _span(empty, "plan", b, b + 0.1, step=0)
-    assert mfu_goodput(empty.to_chrome())["n_waves"] == 0
+    _span(empty, "plan", b, b + 0.1)
+    assert goodput(empty.to_chrome()) == {"n_steps": 0, "goodput": None}
 
 
 def test_analyze_cli_merges_and_reports(tmp_path, capsys):
@@ -484,7 +485,6 @@ def test_analyze_cli_merges_and_reports(tmp_path, capsys):
     _span(ta, "ctrl_step", b, b + 0.2, step=0)
     tb = Tracer(enabled=True, process="worker", pid=1)
     _span(tb, "wave", b + 0.01, b + 0.15, step=0, idx=0,
-          cost_max=0.1, cost_sum=0.3, tokens=64,
           composition=[1, 1, 1, 1], fresh=False)
     p1, p2 = tmp_path / "a.json", tmp_path / "b.json"
     ta.to_chrome(str(p1))
@@ -495,7 +495,7 @@ def test_analyze_cli_merges_and_reports(tmp_path, capsys):
     assert rc == 0
     doc = json.loads(capsys.readouterr().out)
     assert doc["valid"] is True
-    assert doc["mfu"]["n_waves"] == 1
+    assert doc["goodput"]["n_steps"] == 1 and doc["goodput"]["goodput"] > 0
     assert {r["process"] for r in doc["attribution"]} == \
         {"controller", "worker"}
     merged = json.loads(out_path.read_text())

@@ -195,6 +195,7 @@ from repro.configs.registry import get_config
 from repro.data.distribution import LengthDistribution
 from repro.data.loader import GlobalScheduler, SyntheticDataset
 from repro.launch.mesh import hdp_axes_of, make_pipeline_mesh
+from repro.obs import get_tracer
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import Runtime
 from repro.train.trainer import Trainer, TrainerConfig
@@ -210,10 +211,16 @@ sched = GlobalScheduler(ds, cfg, capacity=512, hdp=2, mode="pp",
                         strategy="balance", use_offload=False, num_stages=2)
 tr = Trainer(cfg, rt, AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=50),
              sched, TrainerConfig(capacity=512, mode="pp"))
+get_tracer().enabled = True         # the round path's step spans
 for rec in tr.run(3):
     assert np.isfinite(rec["loss"]), rec
     assert rec["rounds"] >= 1 and 0.0 <= rec["bubble_frac_pipeline"] < 1.0
 assert tr.history[-1]["loss"] < tr.history[0]["loss"], tr.history
+names = {e["name"] for e in get_tracer().snapshot()}
+assert {"train_step", "plan", "grads_init", "materialize", "h2d", "round",
+        "compile", "wave_dispatch", "wave_wait", "wave_post", "apply",
+        "apply_dispatch", "apply_fetch", "step_end"} <= names, names
+assert "wave" not in names, names
 print("PP_TRAINER_OK")
 """
 

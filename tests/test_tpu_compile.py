@@ -160,7 +160,7 @@ def test_one_chip_config_step_fits(topo, chips, impl, composition):
     from repro.models.transformer import init_params
     from repro.optim import adamw
     from repro.parallel.sharding import Runtime
-    from repro.train.train_step import make_accum_steps
+    from repro.train.train_step import make_accum_steps, named_program
     mod = ONE_CHIP["llama3.2-3b-1chip"]
     cfg = mod.CONFIG
     mesh = _mesh(topo, chips)
@@ -182,14 +182,21 @@ def test_one_chip_config_step_fits(topo, chips, impl, composition):
     batch["denom"] = _spec(mesh, (), jnp.float32)
     grad_step, apply_step = make_accum_steps(cfg, rt, adamw.AdamWConfig(),
                                              guard=True)
-    rt_wave = rt.with_composition(composition)
+    wave = named_program("wave", composition, grad_step,
+                         rt.with_composition(composition))
     with compat.use_mesh(mesh):
-        step = jax.jit(lambda p, g, b: grad_step(p, g, b, rt_wave),
-                       donate_argnums=(1,)).lower(params, grads,
-                                                  batch).compile()
+        step = jax.jit(wave, donate_argnums=(1,)).lower(params, grads,
+                                                        batch).compile()
         apply = jax.jit(apply_step, donate_argnums=(2,)).lower(
             params, opt, grads).compile()
+    # the names the profiler's trace shows: the wave's module and each
+    # kernel's custom call
+    hlo = step.as_text()
+    assert f"jit_{wave.__name__}" in hlo
     if impl == "pallas":
         _assert_mosaic(step)
+        for kernel in ("flash_fwd_carry", "flash_dq", "flash_dkv",
+                       "fused_ce_fwd", "fused_ce_bwd"):
+            assert kernel in hlo, kernel
     assert _footprint(step, resident=_bytes(opt)) <= V5E_HBM
     assert _footprint(apply) <= V5E_HBM
