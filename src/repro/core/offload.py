@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import Callable, Optional
 
 from repro.configs.base import ModelConfig
 
@@ -159,6 +160,29 @@ def ratio_for_d(cfg_or_coeffs, s: int, capacity: int, num_layers: int,
     if r > max_overlap_ratio(c, s, hw) + 1e-9:
         return None
     return r
+
+
+# ---------------------------------------------------------------------------
+# remat policy of a wave program: "auto" keeps activations when they fit
+# ---------------------------------------------------------------------------
+
+def resolve_remat(remat: str, bytes_limit: Optional[int],
+                  peak: Callable[[], Optional[int]], *,
+                  pipelined: bool = False, offload: bool = False) -> str:
+    """The remat policy a wave program runs.  Every value but "auto"
+    passes through.  "auto" is "none" when ``peak()`` — the device bytes
+    the program needs without remat, from the compiler's memory count of
+    it plus what stays resident beside it — is at most ``bytes_limit``,
+    else "full".  It is "full" without asking ``peak`` where the backend
+    reports no limit, for a pipelined round (left to a later change) and
+    for a wave that offloads (Eq. 3's path decides its saves); and
+    "full" where ``peak()`` is None (the compiler refused the program)."""
+    if remat != "auto":
+        return remat
+    if pipelined or offload or not bytes_limit:
+        return "full"
+    need = peak()
+    return "none" if need is not None and need <= bytes_limit else "full"
 
 
 def scan_periods(cfg: ModelConfig) -> int:

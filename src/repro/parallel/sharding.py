@@ -40,7 +40,11 @@ class Runtime:
                                       # (fused ring-flash engine)
     attn_block_q: int = 256           # Pallas flash tile shapes (clamped to
     attn_block_k: int = 512           # the local chunk when it is smaller)
-    remat: str = "full"               # none | full | offload
+    remat: str = "auto"               # auto | none | full | dots | offload:
+                                      # the trainer resolves "auto" per wave
+                                      # program from the compiler's memory
+                                      # count (core/offload.resolve_remat);
+                                      # unresolved it runs as "full"
     offload_periods: int = 0          # leading layer-periods whose residuals offload
     kv_chunk: int = 1024
     block_skip: bool = True

@@ -23,7 +23,7 @@ import contextlib
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ from repro import compat
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.obs import numerics as numerics_mod
 from repro.configs.base import ModelConfig
-from repro.core.offload import offload_periods
+from repro.core.offload import offload_periods, resolve_remat
 from repro.data.loader import GlobalScheduler, WaveMaterializer
 from repro.obs import get_metrics, get_recorder, get_tracer, monotime
 from repro.obs import ledger as ledger_mod
@@ -94,6 +94,83 @@ class TrainerConfig:
                                       # "wave": i} poisons that wave's
                                       # loss denominator with NaN (the
                                       # numerics drill — obs/numerics)
+
+
+def _device_bytes(tree) -> int:
+    """Bytes of ``tree`` that one device holds: the shard shape of each
+    array under its sharding."""
+    return sum(int(np.prod(x.sharding.shard_shape(x.shape)))
+               * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+class _Program:
+    """One cached wave or round executable, called as
+    ``(params, grad_accum, batch)``, and the remat policy it runs.
+
+    ``jit_of(remat)`` jits the program under one policy.  The policy
+    resolves at the first call, which is where the program compiles
+    (`resolve_remat`): for "auto" the "none" variant is compiled for the
+    call's arguments, and it runs if the compiler's memory count of it
+    plus ``resident()`` (the device bytes held outside the program)
+    fits the device's ``bytes_limit``; else the "full" variant runs.
+    jit reuses the compile it was weighed with.  Each resolution counts
+    ``trainer.remat.<policy>``."""
+
+    def __init__(self, name: str, jit_of, remat: str, device,
+                 resident, *, pipelined: bool, offload: bool):
+        self.name = name
+        self.remat = remat                  # resolved at the first call
+        self.peak_bytes: Optional[int] = None   # the "none" variant's
+        self._jit_of, self._device, self._resident = jit_of, device, resident
+        self._pipelined, self._offload = pipelined, offload
+        self._jits: Dict[str, Callable] = {}
+        self._fn: Optional[Callable] = None
+
+    def _jit(self, remat: str):
+        if remat not in self._jits:
+            self._jits[remat] = self._jit_of(remat)
+        return self._jits[remat]
+
+    def _peak(self, args) -> Optional[int]:
+        """Device bytes of the "none" variant at its peak, by the
+        compiler's count, plus the resident bytes; None where the
+        compiler refuses it for want of memory."""
+        try:
+            m = self._jit("none").lower(*args).compile().memory_analysis()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return None
+        self.peak_bytes = int(
+            m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes
+            + self._resident())
+        return self.peak_bytes
+
+    def resolve(self, *args) -> str:
+        """Fix the policy for the program's arguments (arrays or their
+        shapes), once; the first call does it."""
+        if self._fn is None:
+            limit = compat.device_memory_stats(self._device).get(
+                "bytes_limit")
+            # the weighed trace's comm records (obs/ledger) are kept
+            # apart, and count only if its program runs: its first call
+            # traces nothing again then
+            with ledger_mod.capture() as weighed:
+                self.remat = resolve_remat(
+                    self.remat, limit, lambda: self._peak(args),
+                    pipelined=self._pipelined, offload=self._offload)
+            if self.remat == "none":
+                for kind, nbytes in weighed.items():
+                    ledger_mod.record_comm(kind, nbytes)
+            self._fn = self._jit(self.remat)
+            self._jits.clear()
+            get_metrics().counter(f"trainer.remat.{self.remat}").inc()
+        return self.remat
+
+    def __call__(self, *args):
+        self.resolve(*args)
+        return self._fn(*args)
 
 
 class Trainer:
@@ -194,7 +271,9 @@ class Trainer:
                 "runtime": {
                     "hdp": int(self.rt.hdp_size), "tp": int(self.rt.tp),
                     "num_stages": int(self.rt.num_stages),
-                    "remat": self.rt.remat,
+                    "remat": self._remat_resolved(),
+                    "remat_programs": {
+                        p.name: p.remat for p in self._exec_cache.values()},
                     "kv_chunk": int(self.rt.kv_chunk),
                     "attn_impl": self.rt.attn_impl,
                     "seq_parallel": bool(self.rt.seq_parallel),
@@ -233,7 +312,19 @@ class Trainer:
         if scheduler.spec.use_offload and not self.offload_ok:
             scheduler.spec = scheduler.spec.replace(use_offload=False)
 
-    def _wave_rt(self, composition, offload_ratio) -> Runtime:
+    def _remat_resolved(self) -> str:
+        """The manifest's remat: the one policy every cached program
+        runs, else the configured value (``remat_programs`` then names
+        each program's)."""
+        policies = {p.remat for p in self._exec_cache.values()}
+        return policies.pop() if len(policies) == 1 else self.rt.remat
+
+    def _program(self, kind, composition, c_mult, offload_ratio, step_fn,
+                 n_waves: Optional[int] = None) -> _Program:
+        """A wave program, or with ``n_waves`` a pipelined round of that
+        many waves; its remat policy resolves at its first call
+        (`_Program`) against the first local device's ``bytes_limit``,
+        with the AdamW state resident beside it."""
         import dataclasses as dc
         rt_wave = self.rt.with_composition(composition)
         if self.tcfg.attn_impl is not None:
@@ -246,7 +337,20 @@ class Trainer:
                 # against the stage-local period window (core/offload.py)
                 offload_periods=offload_periods(self.cfg, offload_ratio,
                                                 self.rt.num_stages))
-        return rt_wave
+
+        def jit_of(remat):
+            return jax.jit(named_program(kind, composition, step_fn,
+                                         dc.replace(rt_wave, remat=remat)),
+                           donate_argnums=(1,))
+        name = "_".join([kind] + [str(int(c)) for c in composition]
+                        + [f"c{c_mult}", f"r{round(offload_ratio, 2)}"]
+                        + ([f"m{n_waves}"] if n_waves else []))
+        device = next(d for d in self.rt.mesh.devices.flat
+                      if d.process_index == jax.process_index())
+        return _Program(name, jit_of, rt_wave.remat, device,
+                        lambda: _device_bytes(self.opt_state),
+                        pipelined=n_waves is not None,
+                        offload=offload_ratio > 0)
 
     def _wave_fn(self, composition, c_mult, offload_ratio):
         """-> (jitted executable, fresh) — ``fresh`` marks a cache miss
@@ -258,10 +362,8 @@ class Trainer:
         get_metrics().counter("trainer.compile_miss" if fresh
                               else "trainer.compile_hit").inc()
         if fresh:
-            self._exec_cache[key] = jax.jit(
-                named_program("wave", composition, self.grad_step,
-                              self._wave_rt(composition, offload_ratio)),
-                donate_argnums=(1,))
+            self._exec_cache[key] = self._program(
+                "wave", composition, c_mult, offload_ratio, self.grad_step)
         return self._exec_cache[key], fresh
 
     def _round_fn(self, composition, c_mult, offload_ratio, n_waves: int):
@@ -273,10 +375,9 @@ class Trainer:
         get_metrics().counter("trainer.compile_miss" if fresh
                               else "trainer.compile_hit").inc()
         if fresh:
-            self._exec_cache[key] = jax.jit(
-                named_program("round", composition, self.pipeline_grad_step,
-                              self._wave_rt(composition, offload_ratio)),
-                donate_argnums=(1,))
+            self._exec_cache[key] = self._program(
+                "round", composition, c_mult, offload_ratio,
+                self.pipeline_grad_step, n_waves=n_waves)
         return self._exec_cache[key], fresh
 
     def resume_if_possible(self):
@@ -408,7 +509,7 @@ class Trainer:
             t_w = self._clock()
             with (tr.span("compile", step=step, idx=idx,
                           composition=composition)
-                  if fresh else contextlib.nullcontext()):
+                  if fresh else contextlib.nullcontext()) as csp:
                 with tr.span("wave_dispatch", step=step, idx=idx), \
                         (ledger_mod.capture() if capture
                          else contextlib.nullcontext()) as tally:
@@ -416,7 +517,13 @@ class Trainer:
                 with tr.span("wave_wait", step=step, idx=idx):
                     # blocks until the program has run (and compiled)
                     loss = float(metrics["loss"])
+                if fresh:
+                    # the policy the first call resolved (`_Program`)
+                    csp.set("remat", fn.remat)
+                    csp.set("remat_peak_bytes", fn.peak_bytes)
             dt = self._clock() - t_w
+            if fresh:
+                self._publish_manifest()
             if capture:
                 self._ledger_meas[id(fn)] = dict(tally)
             if led is not None:

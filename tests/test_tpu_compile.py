@@ -8,14 +8,22 @@ a pass is a compile, never a chip run.
 
 Covered: the flash kernels at llama3.2-3b attention widths, the fused CE at
 its published 128,256 vocabulary, the ring-flash engine on a 4-chip mesh,
-and the one-chip config's training step, whose memory must fit one chip.
+the one-chip config's training step, whose memory must fit one chip, and
+the remat policy "auto" picks for waves of the benchmark's deepseek-llm-7b
+slice and of a full-depth Qwen2.5-0.5B.
 """
+import dataclasses
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
+from repro.configs.base import ModelConfig
 from repro.configs.registry import ONE_CHIP
 from repro.kernels import flash_attention as FA
 from repro.kernels import fused_ce as CE
@@ -46,7 +54,11 @@ def topo():
         was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
-        yield desc
+        # an earlier test of this worker may have left a CPU mesh as the
+        # global mesh: the described chips' arrays must not resolve there,
+        # so the file runs under the empty mesh
+        with jax.set_mesh(Mesh(np.empty((), dtype=object), ())):
+            yield desc
         jax.config.update("jax_enable_compilation_cache", was)
 
 
@@ -148,24 +160,11 @@ def _footprint(compiled, resident=0):
             - m.alias_size_in_bytes + m.temp_size_in_bytes + resident)
 
 
-@pytest.mark.parametrize("chips,impl,composition", [
-    (1, "ref", (1,)),
-    (1, "pallas", (1,)),
-    (4, "pallas", (4,)),              # the chip_smoke --four-chips ring
-])
-def test_one_chip_config_step_fits(topo, chips, impl, composition):
-    """The trainer's per-wave grad step (accumulator donated) plus the
-    resident AdamW state fits one chip at the config's CAPACITY, and so
-    does the optimizer apply (accumulator donated)."""
+def _wave_args(cfg, mesh, rt, tokens):
+    """Shapes of the trainer's per-wave grad step arguments for ``tokens``
+    tokens -> (params, gradient accumulator, batch, AdamW state)."""
     from repro.models.transformer import init_params
     from repro.optim import adamw
-    from repro.parallel.sharding import Runtime
-    from repro.train.train_step import make_accum_steps, named_program
-    mod = ONE_CHIP["llama3.2-3b-1chip"]
-    cfg = mod.CONFIG
-    mesh = _mesh(topo, chips)
-    rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
-                 attn_impl=impl)
     params = jax.eval_shape(lambda k: init_params(k, cfg, rt),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     rep = NamedSharding(mesh, P())
@@ -175,11 +174,19 @@ def test_one_chip_config_step_fits(topo, chips, impl, composition):
     grads = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32, sharding=rep),
         params)
-    opt = jax.eval_shape(adamw.init_state, params)
-    tokens = mod.CAPACITY * chips
     batch = {k: _spec(mesh, (tokens,), jnp.int32, P("data"))
              for k in ("tokens", "labels", "seg", "pos")}
     batch["denom"] = _spec(mesh, (), jnp.float32)
+    return params, grads, batch, jax.eval_shape(adamw.init_state, params)
+
+
+def _compile_wave(cfg, mesh, rt, composition, tokens):
+    """Compile the trainer's per-wave grad step (accumulator donated) and
+    the optimizer apply (accumulator donated) for ``tokens`` tokens ->
+    (wave, apply, the AdamW state's shape tree)."""
+    from repro.optim import adamw
+    from repro.train.train_step import make_accum_steps, named_program
+    params, grads, batch, opt = _wave_args(cfg, mesh, rt, tokens)
     grad_step, apply_step = make_accum_steps(cfg, rt, adamw.AdamWConfig(),
                                              guard=True)
     wave = named_program("wave", composition, grad_step,
@@ -191,12 +198,107 @@ def test_one_chip_config_step_fits(topo, chips, impl, composition):
             params, opt, grads).compile()
     # the names the profiler's trace shows: the wave's module and each
     # kernel's custom call
-    hlo = step.as_text()
-    assert f"jit_{wave.__name__}" in hlo
+    assert f"jit_{wave.__name__}" in step.as_text()
+    return step, apply, opt
+
+
+@pytest.mark.parametrize("chips,impl,composition", [
+    (1, "ref", (1,)),
+    (1, "pallas", (1,)),
+    (4, "pallas", (4,)),              # the chip_smoke --four-chips ring
+])
+def test_one_chip_config_step_fits(topo, chips, impl, composition):
+    """The trainer's per-wave grad step (accumulator donated) plus the
+    resident AdamW state fits one chip at the config's CAPACITY, and so
+    does the optimizer apply (accumulator donated)."""
+    from repro.parallel.sharding import Runtime
+    mod = ONE_CHIP["llama3.2-3b-1chip"]
+    mesh = _mesh(topo, chips)
+    rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
+                 attn_impl=impl, remat="full")
+    step, apply, opt = _compile_wave(mod.CONFIG, mesh, rt, composition,
+                                        mod.CAPACITY * chips)
     if impl == "pallas":
         _assert_mosaic(step)
+        hlo = step.as_text()
         for kernel in ("flash_fwd_carry", "flash_dq", "flash_dkv",
                        "fused_ce_fwd", "fused_ce_bwd"):
             assert kernel in hlo, kernel
     assert _footprint(step, resident=_bytes(opt)) <= V5E_HBM
     assert _footprint(apply) <= V5E_HBM
+
+
+def _benchmark_config(name):
+    """The benchmark's configuration file as the program's ModelConfig
+    (its published config.json keys; a dense SwiGLU decoder)."""
+    path = Path(__file__).resolve().parents[1] / "chipbench" / "configs"
+    c = json.loads((path / f"{name}.json").read_text())
+    return ModelConfig(
+        name=name, family="dense", num_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
+        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
+        tie_embeddings=c["tie_word_embeddings"], dtype=c["torch_dtype"])
+
+
+# Qwen2.5-0.5B (huggingface.co/Qwen/Qwen2.5-0.5B, config.json) at full
+# depth: a whole small model that one chip trains alone.  Its q/k/v biases
+# are left out (2,688 values a layer).
+QWEN25_05B = ModelConfig(
+    name="qwen2.5-0.5b", family="dense", num_layers=24, d_model=896,
+    num_heads=14, num_kv_heads=2, head_dim=64, d_ff=4864,
+    vocab_size=151_936, rope_theta=1e6, norm_eps=1e-6, tie_embeddings=True)
+
+# memory_stats()["bytes_limit"] of one v5e chip, as the chip reports it
+V5E_LIMIT = 16_909_336_064
+
+
+@pytest.mark.parametrize("model,impl,tokens,want", [
+    # the benchmark cell's wave: 2 of 30 layers, no pipeline
+    ("deepseek-llm-7b-1chip", "pallas", 2048, "none"),
+    # the jnp attention keeps its chunk scan's scores and probabilities
+    ("deepseek-llm-7b-1chip", "ref", 2048, "full"),
+    # a document of 8,192 tokens on one chip (c_mult 4)
+    ("deepseek-llm-7b-1chip", "pallas", 8192, "full"),
+    ("qwen2.5-0.5b", "pallas", 2048, "none"),
+    ("qwen2.5-0.5b", "pallas", 4096, "full"),
+])
+def test_auto_remat_on_v5e(topo, monkeypatch, model, impl, tokens, want):
+    """"auto" resolves a wave on a v5e from the compiler's memory count
+    of its "none" variant plus the resident AdamW state, the trainer's
+    own path (`_Program.resolve`): "none" where that fits the chip's
+    ``bytes_limit``, and "full" where it does not, whose program then
+    fits."""
+    from repro.optim import adamw
+    from repro.parallel.sharding import Runtime
+    from repro.train.train_step import make_accum_steps, named_program
+    from repro.train.trainer import _Program
+    cfg = (QWEN25_05B if model == "qwen2.5-0.5b"
+           else _benchmark_config(model))
+    mesh = _mesh(topo, 1)
+    rt = Runtime(mesh=mesh, hdp_axes=("data",), model_axis="model",
+                 attn_impl=impl)
+    assert rt.remat == "auto"
+    args = _wave_args(cfg, mesh, rt, tokens)
+    resident = _bytes(args[3])
+    grad_step, _ = make_accum_steps(cfg, rt, adamw.AdamWConfig(),
+                                    guard=True)
+
+    def jit_of(remat):
+        return jax.jit(named_program("wave", (1,), grad_step,
+                                     dataclasses.replace(rt, remat=remat)),
+                       donate_argnums=(1,))
+    prog = _Program("wave_1", jit_of, rt.remat, None, lambda: resident,
+                    pipelined=False, offload=False)
+    monkeypatch.setattr(compat, "device_memory_stats",
+                        lambda device=None: {"bytes_limit": V5E_LIMIT})
+    with compat.use_mesh(mesh):
+        assert prog.resolve(*args[:3]) == want
+        if want == "none":
+            assert prog.peak_bytes <= V5E_LIMIT
+            return
+        # the "none" program does not fit, or the compiler refused it
+        assert prog.peak_bytes is None or prog.peak_bytes > V5E_LIMIT
+        full = jit_of("full").lower(*args[:3]).compile()
+    assert _footprint(full, resident=resident) <= V5E_LIMIT
