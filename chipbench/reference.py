@@ -20,8 +20,15 @@ The norm weights are stored as offsets from 1 (and decayed towards 1), as
 the program stores them.  Every matmul runs at
 ``jax.default_matmul_precision("highest")``.  Documents are packed into
 rows of ``row_len`` tokens; one jitted call takes one row per device, and
-attention runs in query blocks under remat, so a row of 8,192 tokens fits
-beside the float32 weights, gradients and AdamW moments.
+attention runs in query blocks under remat.  The float32 training state
+(the weights, both AdamW moments, the gradient sum and each call's
+gradient) is sharded over the devices, each leaf on its first dimension
+that the device count divides (`leaf_spec`); the gradient call gathers
+the weights it uses.  On one device that layout is the whole array.  So a
+row of 8,192 tokens a chip fits beside a quarter of the state of a
+four-chip cell's 906M-parameter share
+(`chipbench/tests/test_chipbench_reference.py` compiles it for four v5e
+chips).
 
 ``control=True`` computes every matmul from operands rounded to float8
 (e4m3, scaled per tensor): the precision below the configuration's
@@ -275,6 +282,15 @@ def _adamw(w, g, m, v, step):
     return w, m, v, g, gnorm
 
 
+def leaf_spec(shape, n_dev: int) -> P:
+    """A state leaf's layout over the ``rows`` axis: sharded on its first
+    dimension that ``n_dev`` divides, replicated where none does."""
+    for i, n in enumerate(shape):
+        if n % n_dev == 0:
+            return P(*(None,) * i, "rows")
+    return P()
+
+
 class Reference:
     """Trains the reference for a few steps on ``devices``; keeps the
     per-leaf norms the comparison reads."""
@@ -288,6 +304,13 @@ class Reference:
         self.row_len = traffic.context
         rep = NamedSharding(self.mesh, P())
         self.rows_sharding = NamedSharding(self.mesh, P("rows"))
+        init = partial(init_weights, c=c)
+        shapes = jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+        # the weights, the AdamW moments and every gradient, each leaf
+        # sharded over the devices (`leaf_spec`)
+        self.state_sharding = sh = jax.tree.map(
+            lambda x: NamedSharding(self.mesh, leaf_spec(x.shape, self.n_dev)),
+            shapes)
 
         def loss(w, batch, weight, denom):
             nll = jax.vmap(lambda t, lb, s, p: row_nll(
@@ -299,47 +322,61 @@ class Reference:
             return out
 
         with jax.default_matmul_precision("highest"):
+            self._init = jax.jit(init, out_shardings=sh)
             self._grad = jax.jit(jax.value_and_grad(loss),
-                                 out_shardings=(rep, rep))
+                                 out_shardings=(rep, sh))
             self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
-                                donate_argnums=(0,))
-            self._adamw = jax.jit(_adamw, donate_argnums=(0, 1, 2, 3))
-        self._rep = rep
+                                donate_argnums=(0,), out_shardings=sh)
+            self._adamw = jax.jit(_adamw, donate_argnums=(0, 1, 2, 3),
+                                  out_shardings=(sh, sh, sh, sh, rep))
+
+    def init(self, seed: int):
+        """-> (w, m, v): the weights drawn from ``seed`` and zero AdamW
+        moments, laid out as `state_sharding` says."""
+        with jax.default_matmul_precision("highest"):
+            w = self._init(jax.random.PRNGKey(seed))
+            # zeros_like keeps each leaf's sharding
+            return (w, jax.tree.map(jnp.zeros_like, w),
+                    jax.tree.map(jnp.zeros_like, w))
+
+    def step(self, w, m, v, s: int):
+        """Trains step ``s`` (from 0); donates ``w``, ``m`` and ``v``.  ->
+        (w, m, v, loss, global norm of the gradient before clipping, the
+        clipped gradient)."""
+        with jax.default_matmul_precision("highest"):
+            arr, denom, weight = step_rows(self.traffic, s, self.row_len,
+                                           self.n_dev, self.fault)
+            g, total = None, 0.0
+            for r0 in range(0, arr["tokens"].shape[0], self.n_dev):
+                batch = {k: jax.device_put(a[r0:r0 + self.n_dev],
+                                           self.rows_sharding)
+                         for k, a in arr.items()}
+                wt = jax.device_put(weight[r0:r0 + self.n_dev],
+                                    self.rows_sharding)
+                lv, gi = self._grad(w, batch, wt, jnp.float32(denom))
+                total += float(lv)
+                g = gi if g is None else self._add(g, gi)
+            w, m, v, gc, gn = self._adamw(w, g, m, v, jnp.float32(s + 1))
+            return w, m, v, total, float(gn), gc
 
     def run(self, seed: int, steps: int) -> Dict:
         """-> {"loss": [per step], "gnorm": [per step, the global norm of
         the gradient before clipping], "grad1": {leaf: norm of the first
         clipped gradient}, "delta": {leaf: norm of the change after
         ``steps``}}."""
-        with jax.default_matmul_precision("highest"):
-            w = jax.jit(partial(init_weights, c=self.c),
-                        out_shardings=self._rep)(jax.random.PRNGKey(seed))
-            w0 = jax.device_get(w)          # on the host: HBM is full
-            m = jax.tree.map(jnp.zeros_like, w)
-            v = jax.tree.map(jnp.zeros_like, w)
-            losses, gnorms, grad1 = [], [], None
-            for s in range(steps):
-                arr, denom, weight = step_rows(self.traffic, s, self.row_len,
-                                               self.n_dev, self.fault)
-                g, total = None, 0.0
-                for r0 in range(0, arr["tokens"].shape[0], self.n_dev):
-                    batch = {k: jax.device_put(a[r0:r0 + self.n_dev],
-                                               self.rows_sharding)
-                             for k, a in arr.items()}
-                    wt = jax.device_put(weight[r0:r0 + self.n_dev],
-                                        self.rows_sharding)
-                    lv, gi = self._grad(w, batch, wt, jnp.float32(denom))
-                    total += float(lv)
-                    g = gi if g is None else self._add(g, gi)
-                losses.append(total)
-                w, m, v, gc, gn = self._adamw(w, g, m, v, jnp.float32(s + 1))
-                gnorms.append(float(gn))
-                if s == 0:
-                    grad1 = leaf_norms(gc)
-                del g, gc
-            del m, v
-            w = jax.device_get(w)
-            delta = host_delta_norms(w, w0)
+        w, m, v = self.init(seed)
+        w0 = jax.device_get(w)              # on the host: HBM is full
+        losses, gnorms, grad1 = [], [], None
+        for s in range(steps):
+            w, m, v, lv, gn, gc = self.step(w, m, v, s)
+            losses.append(lv)
+            gnorms.append(gn)
+            if s == 0:
+                grad1 = leaf_norms(gc)
+            del gc
+        del m, v
+        w = jax.device_get(w)
+        delta = host_delta_norms(w, w0)
         return {"loss": losses, "gnorm": gnorms, "grad1": grad1,
                 "delta": delta}
 
