@@ -293,8 +293,9 @@ def _safe(x) -> Optional[float]:
 class NumericsMonitor:
     """Online numerics detector for one trainer.
 
-    ``observe_wave`` runs on every already-fetched wave loss (free: the
-    trainer blocks on that float anyway); ``observe_step`` runs on the
+    ``observe_wave`` runs on every fetched wave loss, in wave order,
+    before the step returns (free: the trainer reads that float anyway,
+    one wave late while the next wave runs); ``observe_step`` runs on the
     fused sentinel summary after the apply.  Both return finding dicts
     (possibly empty) shaped for obs/anomaly.py's numerics channel."""
 

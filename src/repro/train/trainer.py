@@ -22,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Tuple
 
@@ -173,6 +174,35 @@ class _Program:
         return self._fn(*args)
 
 
+@dataclass
+class _Wave:
+    """One dispatch of a wave (or a pipelined round of waves) through
+    `Trainer._dispatch`: what it runs, what its fetch and `_wave_post`
+    need, and what they learn (host clock for every time)."""
+    name: str                        # its span: "wave" | "round"
+    idx: int
+    fn: _Program
+    fresh: bool                      # the program's first call
+    waves: list                      # the plan's waves it runs
+    composition: Tuple[int, ...]
+    c_mult: int = 1
+    offload_ratio: float = 0.0
+    span: object = None              # its wave / round span
+    metrics: Optional[Dict] = None   # the program's device outputs
+    t_ret: float = 0.0               # its dispatch's return
+    ready: Optional[Future] = None   # -> its completion (`_ready_at`)
+    loss: float = float("nan")       # fetched
+    seconds: float = 0.0             # its own time (`Trainer._fetch`)
+
+
+def _ready_at(x, clock) -> float:
+    """The host clock the moment device array ``x`` is ready.  Runs on
+    the trainer's watcher thread, so a wave's completion is stamped
+    while the step loop does host work (or stalls) after the dispatch."""
+    x.block_until_ready()
+    return clock()
+
+
 class Trainer:
     def __init__(self, cfg: ModelConfig, rt: Runtime, opt_cfg: adamw.AdamWConfig,
                  scheduler: GlobalScheduler, tcfg: TrainerConfig,
@@ -244,6 +274,11 @@ class Trainer:
         # step loop (obs.monotime = time.perf_counter); wall clock only
         # appears as the human-readable ``t_wall`` record field
         self._clock = monotime
+        self._t_fetched = 0.0        # the last fetched wave's completion
+        # stamps each dispatched wave's completion (`_ready_at`), in
+        # dispatch order: a wave's time then ends when the chip finished
+        # it, not when the host came back to read it
+        self._watcher = ThreadPoolExecutor(1, "wave-watch")
         # numerics observatory: online monitor + per-step provenance
         # (obs/numerics.py).  ``last_numerics`` / ``last_wave_findings``
         # are ctrl-worker hooks: the step summary rides step_done, wave
@@ -448,12 +483,13 @@ class Trainer:
         ``measured`` is the SPMD wall time (float) or a per-rank time
         vector (the `wave_time_fn` fault-injection clock supplies one).
         The telemetry hook (ctrl worker agent) sees EVERY dispatch with
-        the TRUE ``fresh`` flag and the TRUE host wall ``wall_s`` —
-        downstream consumers (anomaly gap cursor, straggler join) must
-        know a compile sits in the cadence and how long the dispatch
-        really blocked, even when ``measured`` itself is a modeled
-        vector.  ``modeled`` times carry no compile pollution, so the
-        local calibrator ingests them on fresh waves too."""
+        the TRUE ``fresh`` flag and the TRUE host wall ``wall_s``, the
+        dispatch's own time on the host clock (`_fetch`) — downstream
+        consumers (anomaly gap cursor, straggler join) must know a
+        compile sits in the cadence and how long the chip ran the
+        dispatch, even when ``measured`` itself is a modeled vector.
+        ``modeled`` times carry no compile pollution, so the local
+        calibrator ingests them on fresh waves too."""
         if self.telemetry_fn is not None:
             self.telemetry_fn(waves, measured, fresh_compile,
                               wall_s=wall_s)
@@ -487,70 +523,82 @@ class Trainer:
             self.ledger.set_step_bytes(zero1_bytes(self.params, self.rt))
         return self.ledger
 
-    def _dispatch(self, tr, step: int, fn, grads, batch, name: str,
-                  idx: int, composition, fresh: bool, c_mult: int = 1,
-                  offload_ratio: float = 0.0, n_waves: int = 1):
-        """Run one jitted executable under a span: ``wave_dispatch`` the
-        call up to its return, ``wave_wait`` the blocking fetch of its
-        loss; a fresh cache entry pays its compile inside both, under
-        the nested "compile" span.
+    def _dispatch(self, tr, mx, step: int, prev: Optional[_Wave],
+                  w: _Wave, grads, batch, *, last: bool):
+        """Dispatch one wave (or pipelined round) ``w`` under its span and
+        fetch the losses that are due, each under a ``wave_wait`` span
+        stamped with the ``idx`` of the wave it fetches; each fetched
+        wave then gets its ``wave_post``, in wave order.  -> (grads, the
+        wave still in flight or None, the fetched waves).
+
+        A warm wave's loss is read one wave late: ``prev`` (the wave
+        before, still in flight) is fetched after ``w`` is dispatched,
+        so the chip runs ``w`` while the host prepares the next wave.
+        The gradient accumulator flows from wave to wave by donation,
+        so the chip runs the waves in the same order either way.  A
+        fresh program (its first call compiles and resolves its remat
+        policy) fetches ``prev`` before it and its own loss at once,
+        under the nested ``compile`` span; the step's last wave fetches
+        its own before the apply is dispatched.
 
         Bytes ledger: a fresh compile's trace runs under
         ``ledger.capture()``, harvesting the instrumented comm sites'
         static byte counts into a per-executable tally; warm dispatches
-        re-stamp the cached tally.  Every dispatch then lands one
-        predicted/measured record (plus an allocator HBM peak sample
-        where the backend exposes one) on the ledger, the span, and
-        ``last_ledger_record`` for the ctrl worker's heartbeat."""
+        re-stamp the cached tally (`_wave_post`)."""
         led = self._ensure_ledger()
-        capture = fresh and led is not None
-        with tr.span(name, step=step, idx=idx, composition=composition,
-                     fresh=fresh) as sp:
-            t_w = self._clock()
-            with (tr.span("compile", step=step, idx=idx,
-                          composition=composition)
-                  if fresh else contextlib.nullcontext()) as csp:
-                with tr.span("wave_dispatch", step=step, idx=idx), \
+        capture = w.fresh and led is not None
+        done = []
+        with tr.span(w.name, step=step, idx=w.idx,
+                     composition=w.composition, fresh=w.fresh) as sp:
+            w.span = sp
+            if w.fresh and prev is not None:
+                done.append(self._fetch(tr, mx, step, prev, deferred=False))
+                prev = None
+            with (tr.span("compile", step=step, idx=w.idx,
+                          composition=w.composition)
+                  if w.fresh else contextlib.nullcontext()) as csp:
+                with tr.span("wave_dispatch", step=step, idx=w.idx), \
                         (ledger_mod.capture() if capture
                          else contextlib.nullcontext()) as tally:
-                    grads, metrics = fn(self.params, grads, batch)
-                with tr.span("wave_wait", step=step, idx=idx):
-                    # blocks until the program has run (and compiled)
-                    loss = float(metrics["loss"])
-                if fresh:
+                    grads, w.metrics = w.fn(self.params, grads, batch)
+                w.t_ret = self._clock()
+                w.ready = self._watcher.submit(
+                    _ready_at, w.metrics["loss"], self._clock)
+                if capture:
+                    self._ledger_meas[id(w.fn)] = dict(tally)
+                if prev is not None:
+                    done.append(self._fetch(tr, mx, step, prev, deferred=True))
+                if w.fresh or last:
+                    done.append(self._fetch(tr, mx, step, w, deferred=False))
+                if w.fresh:
                     # the policy the first call resolved (`_Program`)
-                    csp.set("remat", fn.remat)
-                    csp.set("remat_peak_bytes", fn.peak_bytes)
-            dt = self._clock() - t_w
-            if fresh:
+                    csp.set("remat", w.fn.remat)
+                    csp.set("remat_peak_bytes", w.fn.peak_bytes)
+            if w.fresh:
                 self._publish_manifest()
-            if capture:
-                self._ledger_meas[id(fn)] = dict(tally)
-            if led is not None:
-                hbm = compat.device_memory_stats().get("peak_bytes_in_use")
-                rec = led.record_dispatch(
-                    step=self.step, idx=idx, kind=name,
-                    composition=composition, c_mult=c_mult,
-                    offload_ratio=offload_ratio, n_waves=n_waves,
-                    fresh=fresh, measured=self._ledger_meas.get(id(fn)),
-                    hbm_peak=hbm)
-                self.last_ledger_record = rec
-                sp.set("bytes_pred", rec["pred"])
-                mx = get_metrics()
-                mx.counter("comm.pred_bytes").inc(
-                    sum(rec["pred"].values()))
-                mx.gauge("mem.hbm_pred_peak").set(float(rec["hbm_pred"]))
-                if hbm is not None:
-                    mx.gauge("mem.hbm_meas_peak").set(float(hbm))
-                if "meas" in rec:
-                    sp.set("bytes_meas", rec["meas"])
-                    mx.counter("comm.meas_bytes").inc(
-                        sum(rec["meas"].values()))
-                    self.calib.observe_bytes(
-                        sum(rec["pred"].values()),
-                        sum(rec["meas"].values()))
-                    mx.gauge("comm.residual").set(led.comm_residual())
-        return grads, loss, dt
+        for d in done:
+            with tr.span("wave_post", step=step, idx=d.idx):
+                self._wave_post(mx, d)
+        return grads, None if w.fresh or last else w, done
+
+    def _fetch(self, tr, mx, step: int, w: _Wave,
+               deferred: bool) -> _Wave:
+        """Block on one dispatched wave's loss (the ``wave_wait`` span)
+        and time it: ``w.seconds`` is its completion, as the watcher
+        stamped it, less the later of its dispatch's return and the
+        previous wave's completion.  That is the wave's own time on the
+        host clock: host work or a stall between its completion and
+        this fetch stays out of it, in the gap before the next record.
+        Counts ``trainer.wave_fetch.deferred`` where the next wave was
+        dispatched first, else ``.immediate``."""
+        with tr.span("wave_wait", step=step, idx=w.idx):
+            t_done = w.ready.result()
+            w.loss = float(w.metrics["loss"])
+        w.seconds = t_done - max(w.t_ret, self._t_fetched)
+        self._t_fetched = t_done
+        mx.counter("trainer.wave_fetch."
+                   + ("deferred" if deferred else "immediate")).inc()
+        return w
 
     # -- numerics observatory hooks ------------------------------------
 
@@ -579,11 +627,11 @@ class Trainer:
 
     def train_step(self) -> Dict:
         """One global batch: plan; per wave (or pipelined round)
-        materialize its buffers, copy them in, dispatch and observe;
-        then one optimizer apply.  With tracing on, every host stretch
-        of the step is a span (README, "Observability") inside one
-        ``train_step`` span, which also marks the step on the
-        profiler's ``Steps`` line."""
+        materialize its buffers, copy them in, dispatch, then fetch and
+        observe the wave before it (`_dispatch`); then one optimizer
+        apply.  With tracing on, every host stretch of the step is a span
+        (README, "Observability") inside one ``train_step`` span, which
+        also marks the step on the profiler's ``Steps`` line."""
         tr = get_tracer()
         with tr.step_span("train_step", self.step, "train"):
             return self._train_step(tr, self.step)
@@ -602,6 +650,7 @@ class Trainer:
             grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  self.params)
         losses = []
+        prev = None     # the wave in flight whose loss is still unread
         n_rounds = None
         if self.pipelined:
             # pipelined executor: the wave queue runs as rounds of like
@@ -627,14 +676,13 @@ class Trainer:
                 fn, fresh = self._round_fn(rd.composition, rd.c_mult,
                                            rd.offload_ratio,
                                            len(rd.wave_ids))
-                rd_waves = [plan.waves[i] for i in rd.wave_ids]
-                grads, loss, dt = self._dispatch(
-                    tr, step, fn, grads, batch, "round", i, rd.composition,
-                    fresh, c_mult=rd.c_mult, offload_ratio=rd.offload_ratio,
-                    n_waves=len(rd.wave_ids))
-                with tr.span("wave_post", step=step, idx=i):
-                    losses.append(loss)
-                    self._wave_post(mx, i, loss, dt, fresh, rd_waves)
+                w = _Wave("round", i, fn, fresh,
+                          [plan.waves[j] for j in rd.wave_ids],
+                          rd.composition, rd.c_mult, rd.offload_ratio)
+                grads, prev, done = self._dispatch(
+                    tr, mx, step, prev, w, grads, batch,
+                    last=i == len(rounds) - 1)
+                losses += [d.loss for d in done]
             for _ in round_iter:        # drain the prefetch epilogue so
                 pass                    # producer errors still surface
         else:
@@ -643,17 +691,16 @@ class Trainer:
             for i in range(len(plan.waves)):
                 with tr.span("materialize", step=step, idx=i):
                     lw = next(wave_iter)
-                wave = plan.waves[i]
                 with tr.span("h2d", step=step, idx=i):
                     batch = self._to_device(lw.batch, i, denom)
                 fn, fresh = self._wave_fn(lw.composition, lw.c_mult,
                                           lw.offload_ratio)
-                grads, loss, dt = self._dispatch(
-                    tr, step, fn, grads, batch, "wave", i, lw.composition,
-                    fresh, c_mult=lw.c_mult, offload_ratio=lw.offload_ratio)
-                with tr.span("wave_post", step=step, idx=i):
-                    losses.append(loss)
-                    self._wave_post(mx, i, loss, dt, fresh, [wave])
+                w = _Wave("wave", i, fn, fresh, [plan.waves[i]],
+                          lw.composition, lw.c_mult, lw.offload_ratio)
+                grads, prev, done = self._dispatch(
+                    tr, mx, step, prev, w, grads, batch,
+                    last=i == len(plan.waves) - 1)
+                losses += [d.loss for d in done]
             for _ in wave_iter:         # drain the prefetch epilogue so
                 pass                    # producer errors still surface
         with tr.span("apply", step=step):
@@ -686,20 +733,51 @@ class Trainer:
             float("nan") if self._nan_fault_hits(idx) else denom)
         return batch
 
-    def _wave_post(self, mx, idx: int, loss: float, dt: float,
-                   fresh: bool, waves) -> None:
-        """The host's work after one dispatch (the ``wave_post`` span):
-        the numerics monitor, the dispatch histogram and `_observe`.
-        The `wave_time_fn` hook prices a round's waves, or one wave."""
+    def _wave_post(self, mx, w: _Wave) -> None:
+        """The host's work after one fetched dispatch (the ``wave_post``
+        span): the numerics monitor, the dispatch histogram and
+        `_observe`, all fed the wave's own time ``w.seconds``.  The
+        `wave_time_fn` hook prices a round's waves, or one wave.
+
+        With the ledger on, lands the dispatch's predicted/measured
+        record (plus an allocator HBM peak sample where the backend
+        exposes one) on the ledger, on the wave's span (its event keeps
+        the span's ``args``, so stamps set after a late fetch still
+        land) and on ``last_ledger_record`` for the ctrl worker's
+        heartbeat."""
         self.last_wave_findings = \
-            self.numerics.observe_wave(self.step, idx, loss)
+            self.numerics.observe_wave(self.step, w.idx, w.loss)
         self._note_findings(self.last_wave_findings, mx)
-        mx.histogram("trainer.dispatch_s").observe(dt)
-        wall = dt
+        mx.histogram("trainer.dispatch_s").observe(w.seconds)
+        led = self._ensure_ledger()
+        if led is not None:
+            hbm = compat.device_memory_stats().get("peak_bytes_in_use")
+            rec = led.record_dispatch(
+                step=self.step, idx=w.idx, kind=w.name,
+                composition=w.composition, c_mult=w.c_mult,
+                offload_ratio=w.offload_ratio, n_waves=len(w.waves),
+                fresh=w.fresh, measured=self._ledger_meas.get(id(w.fn)),
+                hbm_peak=hbm)
+            self.last_ledger_record = rec
+            w.span.set("bytes_pred", rec["pred"])
+            mx.counter("comm.pred_bytes").inc(sum(rec["pred"].values()))
+            mx.gauge("mem.hbm_pred_peak").set(float(rec["hbm_pred"]))
+            if hbm is not None:
+                mx.gauge("mem.hbm_meas_peak").set(float(hbm))
+            if "meas" in rec:
+                w.span.set("bytes_meas", rec["meas"])
+                mx.counter("comm.meas_bytes").inc(
+                    sum(rec["meas"].values()))
+                self.calib.observe_bytes(sum(rec["pred"].values()),
+                                         sum(rec["meas"].values()))
+                mx.gauge("comm.residual").set(led.comm_residual())
+        dt = w.seconds
         if self.wave_time_fn is not None:
-            dt = self.wave_time_fn(waves if self.pipelined else waves[0])
-        self._observe(waves, dt, fresh,
-                      modeled=self.wave_time_fn is not None, wall_s=wall)
+            dt = self.wave_time_fn(w.waves if self.pipelined
+                                   else w.waves[0])
+        self._observe(w.waves, dt, w.fresh,
+                      modeled=self.wave_time_fn is not None,
+                      wall_s=w.seconds)
 
     def _end_step(self, plan, losses, om, t0: float, n_find0: int,
                   n_rounds: Optional[int], mx) -> Dict:

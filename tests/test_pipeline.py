@@ -186,7 +186,8 @@ np.testing.assert_allclose(total_pp, total_ref, rtol=2e-2)
 print("PP_PARITY_OK", total_pp, total_ref)
 """
 
-TRAINER_SCRIPT = r"""
+# a tiny pipelined trainer: 2 stages x 2 HDP ranks x 2-way model axis
+TRAINER_SETUP = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
@@ -211,6 +212,9 @@ sched = GlobalScheduler(ds, cfg, capacity=512, hdp=2, mode="pp",
                         strategy="balance", use_offload=False, num_stages=2)
 tr = Trainer(cfg, rt, AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=50),
              sched, TrainerConfig(capacity=512, mode="pp"))
+"""
+
+TRAINER_SCRIPT = TRAINER_SETUP + r"""
 get_tracer().enabled = True         # the round path's step spans
 for rec in tr.run(3):
     assert np.isfinite(rec["loss"]), rec

@@ -18,7 +18,7 @@ from repro.obs import (Tracer, get_metrics, get_recorder, get_tracer,
                        set_tracer)
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import single_device_runtime
-from repro.train.trainer import Trainer, TrainerConfig
+from repro.train.trainer import Trainer, TrainerConfig, _Wave
 
 LIMIT = 16 * 10 ** 9
 
@@ -92,8 +92,11 @@ def _wave(tr, limit):
             fn, fresh = tr._wave_fn(lw.composition, lw.c_mult,
                                     lw.offload_ratio)
             assert fresh
-            grads, loss, _ = tr._dispatch(tracer, 0, fn, zero, batch,
-                                          "wave", 0, lw.composition, fresh)
+            w = _Wave("wave", 0, fn, fresh, plan.waves[:1], lw.composition,
+                      lw.c_mult, lw.offload_ratio)
+            grads, _, (done,) = tr._dispatch(tracer, mx, 0, None, w, zero,
+                                             batch, last=True)
+            loss = done.loss
     finally:
         set_tracer(prev)
     span = [e for e in tracer.snapshot()

@@ -3,8 +3,6 @@ is covered by a span nested in one ``train_step`` span, tracing off
 records nothing, tracing on turns on no other work (the bytes ledger),
 and under a `jax.profiler` session the spans land on the profiler's host
 plane with the tracer's durations."""
-import re
-
 import jax
 import pytest
 
@@ -19,9 +17,33 @@ from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import single_device_runtime
 from repro.train.trainer import Trainer, TrainerConfig
 
-# a wave step's direct children of ``train_step``, in order
-CHILDREN = re.compile(r"plan grads_init( materialize h2d wave wave_post)+"
-                      r" apply step_end checkpoint")
+
+def CHILDREN(fresh):
+    """A wave step's direct children of ``train_step``, in order, for
+    waves whose programs are ``fresh`` (each wave's ``fresh`` flag): a
+    warm wave's ``wave_post`` follows the next wave's span, which
+    fetches its loss; a fresh program's and the last wave's follow
+    their own."""
+    out = ["plan", "grads_init"]
+    for i, f in enumerate(fresh):
+        late = i > 0 and not fresh[i - 1]
+        out += ["materialize", "h2d", "wave"]
+        out += ["wave_post"] * (late + (f or i == len(fresh) - 1))
+    return out + ["apply", "step_end", "checkpoint"]
+
+
+def WAVE_CHILDREN(i, fresh):
+    """Wave ``i``'s nested spans, in order, as (name, idx): the late
+    ``wave_wait`` of wave i-1 after this wave's dispatch, or before a
+    fresh program's compile, which dispatches and fetches its own."""
+    late = [("wave_wait", i - 1)] if i > 0 and not fresh[i - 1] else []
+    if fresh[i]:
+        return late + [("compile", i), ("wave_dispatch", i),
+                       ("wave_wait", i)]
+    own = [("wave_wait", i)] if i == len(fresh) - 1 else []
+    return [("wave_dispatch", i)] + late + own
+
+
 # nested spans and the spans they sit directly in
 PARENTS = {"wave_dispatch": ("wave", "compile"),
            "wave_wait": ("wave", "compile"), "compile": ("wave",),
@@ -92,8 +114,18 @@ def test_train_step_spans_cover_the_step(trainer, tracer):
     assert ok, problems
     tree = _tree(spans)
     (top, _, children), = [n for n in tree if n[0]["name"] == "train_step"]
-    assert CHILDREN.fullmatch(" ".join(c["name"] for c in children)), \
-        [c["name"] for c in children]
+    waves = [n for n in tree if n[0]["name"] == "wave"]
+    fresh = [e["args"]["fresh"] for e, _, _ in waves]
+    assert any(fresh) and not all(fresh), fresh   # both kinds of wave
+    assert [c["name"] for c in children] == CHILDREN(fresh)
+    for i, (e, _, _) in enumerate(waves):
+        assert e["args"]["idx"] == i, e
+        nested = [n for n in tree if e["ts"] <= n[0]["ts"] < e["ts"]
+                  + e["dur"] and n[0] is not e]
+        assert [(n[0]["name"], n[0]["args"]["idx"]) for n in nested] \
+            == WAVE_CHILDREN(i, fresh), (i, nested)
+    assert [c["args"]["idx"] for c in children
+            if c["name"] == "wave_post"] == list(range(len(waves)))
     for e, parent, _ in tree:
         if e["name"] not in TRAINER_SPANS:
             continue                  # the scheduler's own, inside plan
